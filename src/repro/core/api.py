@@ -27,6 +27,16 @@ from repro.core.scaling import (
 from repro.core.srr import srr_decompose
 from repro.quant import QuantizerConfig, make_quantizer
 
+# The offline pass (calibration moments, the scaling eigh, the SVDs) runs
+# its f32 matmuls at full precision: the TPU default is bf16 passes,
+# which can shift the chosen k*. Serving never enters this scope.
+PTQ_MATMUL_PRECISION = "highest"
+
+
+def ptq_precision():
+    """Context manager pinning the matmul precision of the offline pass."""
+    return jax.default_matmul_precision(PTQ_MATMUL_PRECISION)
+
 
 class CalibStats(NamedTuple):
     """Streaming per-layer input statistics. All in float32."""
@@ -108,48 +118,57 @@ def quantize_layer(
     key: jax.Array,
     quantizer=None,
     recorder=None,
+    scaling: Optional[Scaling] = None,
 ) -> tuple[Decomposition, LayerReport]:
     """Apply the configured method to one weight matrix.
+
+    ``scaling`` is S built from ``stats`` by the caller, so matrices that
+    share one calibration stream share one eigendecomposition.
 
     ``recorder`` is an optional duck-typed observer (see
     :mod:`repro.obs.quant`) whose ``record_layer`` hook receives the
     inputs and results of every pass; this module never imports the
     observability package.
     """
-    t0 = time.perf_counter()
-    scaling = (stats.scaling(cfg.scaling) if stats is not None
-               else identity_scaling())
-    if quantizer is None:
-        quantizer = make_quantizer(cfg.quantizer)
-    rank = cfg.rank_for(w.shape)
+    with ptq_precision():
+        t0 = time.perf_counter()
+        if scaling is None:
+            scaling = (stats.scaling(cfg.scaling) if stats is not None
+                       else identity_scaling())
+        if quantizer is None:
+            quantizer = make_quantizer(cfg.quantizer)
+        rank = cfg.rank_for(w.shape)
 
-    if cfg.method == "w-only":
-        dec = w_only(w, quantizer, rank)
-    elif cfg.method == "qer":
-        dec = qer_decompose(w, scaling, quantizer, rank, key=key,
-                            exact=cfg.exact_svd)
-    elif cfg.method in ("srr", "srr-joint"):
-        variant = "joint" if cfg.method == "srr-joint" else "split"
-        res = srr_decompose(w, scaling, quantizer, rank, key,
-                            k=cfg.forced_k, exact=cfg.exact_svd,
-                            variant=variant)
-        dec = res.decomposition
-    elif cfg.method == "none":
-        dec = Decomposition(q=w.astype(jnp.float32),
-                            l=jnp.zeros((w.shape[0], rank), jnp.float32),
-                            r=jnp.zeros((rank, w.shape[1]), jnp.float32), k=0)
-    else:
-        raise ValueError(f"unknown PTQ method {cfg.method!r}")
+        if cfg.method == "w-only":
+            dec = w_only(w, quantizer, rank)
+        elif cfg.method == "qer":
+            dec = qer_decompose(w, scaling, quantizer, rank, key=key,
+                                exact=cfg.exact_svd)
+        elif cfg.method in ("srr", "srr-joint"):
+            variant = "joint" if cfg.method == "srr-joint" else "split"
+            res = srr_decompose(w, scaling, quantizer, rank, key,
+                                k=cfg.forced_k, exact=cfg.exact_svd,
+                                variant=variant)
+            dec = res.decomposition
+        elif cfg.method == "none":
+            dec = Decomposition(
+                q=w.astype(jnp.float32),
+                l=jnp.zeros((w.shape[0], rank), jnp.float32),
+                r=jnp.zeros((rank, w.shape[1]), jnp.float32), k=0)
+        else:
+            raise ValueError(f"unknown PTQ method {cfg.method!r}")
 
-    serr = float(scaled_error(w, dec, scaling))
-    werr = float(jnp.linalg.norm(w.astype(jnp.float32) - dec.reconstruct()))
-    report = LayerReport(
-        name=name, shape=tuple(w.shape), rank=rank, k_star=dec.k,
-        scaled_err=serr, weight_err=werr,
-        seconds=time.perf_counter() - t0,
-    )
-    if recorder is not None:
-        recorder.record_layer(name, w, dec, scaling, cfg, quantizer, report)
+        serr = float(scaled_error(w, dec, scaling))
+        werr = float(jnp.linalg.norm(w.astype(jnp.float32)
+                                     - dec.reconstruct()))
+        report = LayerReport(
+            name=name, shape=tuple(w.shape), rank=rank, k_star=dec.k,
+            scaled_err=serr, weight_err=werr,
+            seconds=time.perf_counter() - t0,
+        )
+        if recorder is not None:
+            recorder.record_layer(name, w, dec, scaling, cfg, quantizer,
+                                  report)
     return dec, report
 
 

@@ -21,6 +21,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 SCALING_KINDS = ("identity", "lqer", "qera-approx", "qera-exact")
 
@@ -78,15 +79,7 @@ def qera_exact_scaling(x: jax.Array, eps: float = 1e-4) -> Scaling:
     paper requires invertible S).
     """
     x = x.astype(jnp.float32)
-    r = (x.T @ x) / x.shape[0]
-    r = 0.5 * (r + r.T)
-    evals, evecs = jnp.linalg.eigh(r)
-    floor = eps * jnp.maximum(evals[-1], 1e-12)
-    evals = jnp.maximum(evals, floor)
-    half = jnp.sqrt(evals)
-    s = (evecs * half) @ evecs.T
-    s_inv = (evecs / half) @ evecs.T
-    return Scaling(dense=s, dense_inv=s_inv)
+    return _sqrt_and_inverse((x.T @ x) / x.shape[0], eps)
 
 
 def autocorr_scaling_from_moments(r: jax.Array, eps: float = 1e-4) -> Scaling:
@@ -96,11 +89,23 @@ def autocorr_scaling_from_moments(r: jax.Array, eps: float = 1e-4) -> Scaling:
     accumulates ``Σ xxᵀ`` per layer across calibration batches (constant
     memory), then builds S once.
     """
-    r = 0.5 * (r + r.T)
-    evals, evecs = jnp.linalg.eigh(r.astype(jnp.float32))
-    floor = eps * jnp.maximum(evals[-1], 1e-12)
-    evals = jnp.maximum(evals, floor)
-    half = jnp.sqrt(evals)
+    return _sqrt_and_inverse(r, eps)
+
+
+def _sqrt_and_inverse(r: jax.Array, eps: float) -> Scaling:
+    """S = R^{1/2} and S⁻¹ from one eigendecomposition of the symmetrized
+    R, eigenvalues floored at ``eps·λ_max``.
+
+    The eigendecomposition runs on the host (LAPACK, float64); the two
+    products with the eigenvectors run on the default device. Eager
+    only: this is an offline calibration step. On a TPU,
+    ``jnp.linalg.eigh`` lowers to a QDWH divide-and-conquer program
+    whose compile alone takes over 10 GB of host memory at 3072²."""
+    r = np.asarray(r, np.float64)
+    evals, evecs = np.linalg.eigh(0.5 * (r + r.T))
+    evals = np.maximum(evals, eps * max(evals[-1], 1e-12))
+    half = jnp.asarray(np.sqrt(evals), jnp.float32)
+    evecs = jnp.asarray(evecs, jnp.float32)
     s = (evecs * half) @ evecs.T
     s_inv = (evecs / half) @ evecs.T
     return Scaling(dense=s, dense_inv=s_inv)

@@ -15,7 +15,7 @@ from typing import Dict, Optional
 
 import jax
 
-from repro.core.api import CalibStats
+from repro.core.api import CalibStats, ptq_precision
 from repro.data.synthetic import DataConfig, host_batch
 from repro.models.linear import Ctx
 
@@ -45,9 +45,10 @@ def capture_calibration(
                 tap[name] = CalibStats.init(m, need_autocorr=False)
             tap[name] = tap[name].update(x)
         ctx.record = record  # type: ignore[method-assign]
-    for step in range(n_batches):
-        batch = host_batch(data_cfg, step)
-        forward_fn(ctx, params, batch, model_cfg)
+    with ptq_precision():
+        for step in range(n_batches):
+            batch = host_batch(data_cfg, step)
+            forward_fn(ctx, params, batch, model_cfg)
     return tap
 
 

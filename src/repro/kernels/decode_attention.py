@@ -50,9 +50,19 @@ from repro.kernels.mxint_matmul import _unpack_tile
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
+def _lane_row(ref) -> jax.Array:
+    """Load a ``(1, …, 1, n)`` block as one ``(1, n)`` lane row."""
+    x = ref[...]
+    return x.reshape(1, x.shape[-1])
+
+
 def _decode_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, *rest,
                    n_s: int, window: int, scale: float, quantized: bool,
                    packed: bool):
+    """``qp_ref`` is the scalar-prefetched (B,) position table in SMEM;
+    ``kp_ref`` and the scale refs hold ``(…, 1, bs)`` blocks, so each
+    block's second-minor dim equals its array's (the Mosaic tiling rule
+    for a one-row block)."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -78,15 +88,15 @@ def _decode_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, *rest,
     if quantized:
         # dequant fused on the (G, bs) score columns — G·bs multiplies
         # instead of bs·hd, and the f32 K tile never materializes
-        s = s * ks_ref[0, 0][None, :]
+        s = s * _lane_row(ks_ref)
     s = s * scale
 
-    qp = qp_ref[0, 0]                                # scalar position
-    kp = kp_ref[0]                                   # (bs,) slot positions
+    qp = qp_ref[pl.program_id(0)]                    # scalar position
+    kp = _lane_row(kp_ref)                           # (1, bs) slot positions
     mask = (kp >= 0) & (kp <= qp)
     if window > 0:
         mask = mask & (qp - kp < window)
-    s = jnp.where(mask[None, :], s, NEG_INF)
+    s = jnp.where(mask, s, NEG_INF)
 
     m_prev, l_prev, acc_prev = m_ref[...], l_ref[...], acc_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -101,7 +111,7 @@ def _decode_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, *rest,
     corr = jnp.exp(m_prev - m_new)
     l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
     if quantized:
-        p = p * vs_ref[0, 0][None, :]                # fold V scales into p
+        p = p * _lane_row(vs_ref)                    # fold V scales into p
     v = v_ref[0, 0]                                  # (bs, hd) / (bs/2, hd)
     if packed:
         v = _unpack_tile(v)
@@ -159,33 +169,42 @@ def flash_decode_bkgd(
         _decode_kernel, n_s=n_s, window=window, scale=float(scale),
         quantized=quantized, packed=packed)
     cdiv = 2 if packed else 1    # packed slot rows hold two codes each
+    # q_pos rides in SMEM as a scalar-prefetch operand; k_pos and the
+    # scales are viewed (…, n_s, 1, bs) so each one-row block spans its
+    # array's second-minor dim, which compiled TPU requires
     in_specs = [
-        pl.BlockSpec((1, 1), lambda bb, hh, ss: (bb, 0)),        # q_pos
-        pl.BlockSpec((1, bs), lambda bb, hh, ss: (bb, ss)),      # k_pos
-        pl.BlockSpec((1, 1, g, hd), lambda bb, hh, ss: (bb, hh, 0, 0)),
-        pl.BlockSpec((1, 1, bs // cdiv, hd), lambda bb, hh, ss: (bb, hh, ss, 0)),
-        pl.BlockSpec((1, 1, bs // cdiv, hd), lambda bb, hh, ss: (bb, hh, ss, 0)),
+        pl.BlockSpec((1, 1, 1, bs), lambda bb, hh, ss, qp: (bb, ss, 0, 0)),
+        pl.BlockSpec((1, 1, g, hd), lambda bb, hh, ss, qp: (bb, hh, 0, 0)),
+        pl.BlockSpec((1, 1, bs // cdiv, hd),
+                     lambda bb, hh, ss, qp: (bb, hh, ss, 0)),
+        pl.BlockSpec((1, 1, bs // cdiv, hd),
+                     lambda bb, hh, ss, qp: (bb, hh, ss, 0)),
     ]
-    args = [q_pos.reshape(b, 1).astype(jnp.int32),
-            k_pos.astype(jnp.int32), q, k, v]
+    args = [q_pos.astype(jnp.int32),
+            k_pos.astype(jnp.int32).reshape(b, n_s, 1, bs), q, k, v]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, 1, bs), lambda bb, hh, ss: (bb, hh, ss)),
-            pl.BlockSpec((1, 1, bs), lambda bb, hh, ss: (bb, hh, ss)),
-        ]
-        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        in_specs += [pl.BlockSpec((1, 1, 1, 1, bs),
+                                  lambda bb, hh, ss, qp: (bb, hh, ss, 0, 0))
+                     ] * 2
+        args += [s_.astype(jnp.float32).reshape(b, kv, n_s, 1, bs)
+                 for s_ in (k_scale, v_scale)]
 
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b, kv, n_s),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda bb, hh, ss: (bb, hh, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, g, hd),
+                               lambda bb, hh, ss, qp: (bb, hh, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),     # running max m
             pltpu.VMEM((g, 1), jnp.float32),     # running sum l
             pltpu.VMEM((g, hd), jnp.float32),    # running accumulator
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
         interpret=interpret,
     )(*args)
 
@@ -193,7 +212,7 @@ def flash_decode_bkgd(
 def _paged_decode_kernel(bt_ref, *args, **kw):
     """Scalar-prefetch wrapper: the block table rode in as prefetch arg
     0 (it steered the index maps); the body is the shared flash-decode
-    kernel, which never needs it."""
+    kernel, which reads only the position table (prefetch arg 1)."""
     del bt_ref
     _decode_kernel(*args, **kw)
 
@@ -250,30 +269,30 @@ def flash_decode_paged(
         _paged_decode_kernel, n_s=nb, window=window, scale=float(scale),
         quantized=quantized, packed=packed)
     cdiv = 2 if packed else 1
+    # same operand views as flash_decode_bkgd, with the page as the block
     in_specs = [
-        pl.BlockSpec((1, 1), lambda bb, hh, ss, bt: (bb, 0)),       # q_pos
-        pl.BlockSpec((1, ps), lambda bb, hh, ss, bt: (bb, ss)),     # k_pos
-        pl.BlockSpec((1, 1, g, hd), lambda bb, hh, ss, bt: (bb, hh, 0, 0)),
+        pl.BlockSpec((1, 1, 1, ps), lambda bb, hh, ss, bt, qp: (bb, ss, 0, 0)),
+        pl.BlockSpec((1, 1, g, hd), lambda bb, hh, ss, bt, qp: (bb, hh, 0, 0)),
         pl.BlockSpec((1, 1, ps // cdiv, hd),
-                     lambda bb, hh, ss, bt: (bt[bb, ss], hh, 0, 0)),
+                     lambda bb, hh, ss, bt, qp: (bt[bb, ss], hh, 0, 0)),
         pl.BlockSpec((1, 1, ps // cdiv, hd),
-                     lambda bb, hh, ss, bt: (bt[bb, ss], hh, 0, 0)),
+                     lambda bb, hh, ss, bt, qp: (bt[bb, ss], hh, 0, 0)),
     ]
-    args = [q_pos.reshape(b, 1).astype(jnp.int32),
-            k_pos.astype(jnp.int32), q, k, v]
+    args = [q_pos.astype(jnp.int32),
+            k_pos.astype(jnp.int32).reshape(b, nb, 1, ps), q, k, v]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, 1, ps), lambda bb, hh, ss, bt: (bt[bb, ss], hh, 0)),
-            pl.BlockSpec((1, 1, ps), lambda bb, hh, ss, bt: (bt[bb, ss], hh, 0)),
-        ]
-        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        in_specs += [pl.BlockSpec((1, 1, 1, ps),
+                                  lambda bb, hh, ss, bt, qp: (bt[bb, ss], hh, 0, 0))
+                     ] * 2
+        args += [s_.astype(jnp.float32)[:, :, None, :]
+                 for s_ in (k_scale, v_scale)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, kv, nb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda bb, hh, ss, bt: (bb, hh, 0, 0)),
+                               lambda bb, hh, ss, bt, qp: (bb, hh, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),     # running max m
             pltpu.VMEM((g, 1), jnp.float32),     # running sum l

@@ -74,7 +74,7 @@ def _check_tiles(m: int, k: int, n: int, bm: int, bk: int, bn: int,
 
 
 def _unpack_tile(packed: jax.Array) -> jax.Array:
-    """packed4 (bk/2, bn) uint8 tile → int8 (bk, bn) codes, in VMEM.
+    """packed4 (bk/2, bn) uint8 tile → int32 (bk, bn) codes, in VMEM.
 
     Row pairs interleave as [lo0, hi0, lo1, hi1, ...] — the layout
     :func:`repro.quant.mxint.pack_codes_4bit` writes — via a stack +
@@ -82,12 +82,14 @@ def _unpack_tile(packed: jax.Array) -> jax.Array:
     tile resident). Sign extension is shift-based (shl + arithmetic shr
     in the i32 working type, 2 ops/nibble) instead of a compare-select
     pair — this runs per (K, N) tile of every fused matmul and per
-    (bs, hd) K/V tile of every int4 flash-decode step. Reading the
-    packed container instead of pre-expanded int8 halves the codes' HBM
-    stream."""
+    (bs, hd) K/V tile of every int4 flash-decode step. The interleave
+    stays in i32: Mosaic refuses the int8 sublane interleave when the
+    lane dim is not a multiple of 128 (hd = 96), and every caller widens
+    to f32 next anyway. Reading the packed container instead of
+    pre-expanded int8 halves the codes' HBM stream."""
     u = packed.astype(jnp.int32)
-    lo = ((u << 28) >> 28).astype(jnp.int8)  # sign-extend 4-bit 2's comp
-    hi = ((u << 24) >> 28).astype(jnp.int8)
+    lo = (u << 28) >> 28                     # sign-extend 4-bit 2's comp
+    hi = (u << 24) >> 28
     m2, bn = packed.shape
     return jnp.stack([lo, hi], axis=1).reshape(m2 * 2, bn)
 

@@ -3,17 +3,10 @@ touches jax device state (device count locks on first jax init)."""
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax ≥ 0.5 distinguishes Auto/Explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: Auto is the only behaviour
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _mk(shape, axes) -> Mesh:
-    if AxisType is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
     return jax.make_mesh(tuple(shape), tuple(axes),
                          axis_types=(AxisType.Auto,) * len(axes))
 

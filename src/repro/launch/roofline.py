@@ -152,8 +152,6 @@ def analyze(compiled, cfg, shape, mesh_name: str, chips: int,
             arch: str) -> Roofline:
     from repro.launch.hlo_cost import analyze_text
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0]
     text = compiled.as_text()
     # while-aware accounting (XLA's cost_analysis counts loop bodies once;
     # see launch.hlo_cost) — the XLA numbers ride along for reference

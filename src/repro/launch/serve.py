@@ -1,7 +1,9 @@
 """Serving driver: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Full paper pipeline on a reduced model: init → calibrate → SRR-quantize
-(W ≈ Q + LR) → serve requests through the continuous-batching engine.
+Full paper pipeline: init → calibrate → SRR-quantize (W ≈ Q + LR) →
+serve requests through the continuous-batching engine. The model is the
+reduced smoke config unless ``--full-size`` asks for the published
+widths; ``--layers N`` cuts depth either way.
 ``--method qer`` / ``--method w-only`` serve the baselines instead;
 ``--kv int8`` exercises the quantized KV cache (``--kv int4`` the
 packed4 nibble cache — half the int8 HBM again); ``--scheduler
@@ -10,6 +12,7 @@ bucketed`` falls back to the prompt-length-bucketed baseline scheduler.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import time
@@ -20,6 +23,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core.api import PTQConfig
 from repro.data import capture_calibration, data_config_for
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_lm, lm_loss
 from repro.models.quantize import quantize_model_params
 from repro.quant.base import QuantizerConfig
@@ -31,6 +35,11 @@ def add_model_args(p: argparse.ArgumentParser) -> None:
     """Model/quantization flags shared by the batch driver here and the
     HTTP server (``repro.launch.server``)."""
     p.add_argument("--arch", default="phi3-mini-3.8b")
+    p.add_argument("--full-size", action="store_true",
+                   help="the config at its published widths (needs the "
+                        "chip); default is the reduced smoke config")
+    p.add_argument("--layers", type=int, default=None,
+                   help="cut depth to N layers; widths stay as configured")
     p.add_argument("--method", default="srr",
                    choices=["srr", "qer", "w-only", "none"])
     p.add_argument("--rank", type=int, default=16)
@@ -46,15 +55,22 @@ def add_model_args(p: argparse.ArgumentParser) -> None:
                         "tools.quant_report PATH")
 
 
-def build_quantized_model(args, tag: str = "serve"):
-    """Init the reduced model and run the paper pipeline (calibrate →
-    quantize) per the shared model flags; returns ``(params, cfg)``.
+def build_quantized_model(args, tag: str = "serve", progress=None):
+    """Init the model the shared flags name (published widths under
+    ``--full-size``, else the reduced smoke config; ``--layers`` cuts
+    depth) and run the paper pipeline (calibrate → quantize) on it;
+    returns ``(params, cfg)``. ``progress``
+    is called with each matrix's :class:`repro.core.api.LayerReport`.
 
     ``--quant-report PATH`` threads a :class:`repro.obs.QuantRecorder`
     through the pass and writes its schema-pinned JSON report (always —
     ``--method none`` yields an empty-layer report, so CI artifact steps
     never conditionally skip)."""
-    cfg = get_config(args.arch).reduced()
+    cfg = get_config(args.arch)
+    if not getattr(args, "full_size", False):
+        cfg = cfg.reduced()
+    if getattr(args, "layers", None):
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     params = init_lm(jax.random.PRNGKey(args.seed), cfg)
     recorder = None
     report_path = getattr(args, "quant_report", None)
@@ -75,6 +91,7 @@ def build_quantized_model(args, tag: str = "serve"):
                         seed=args.seed)
         t0 = time.perf_counter()
         params, reports = quantize_model_params(params, stats, ptq,
+                                                progress=progress,
                                                 recorder=recorder)
         print(f"[{tag}] {args.method} quantized {len(reports)} matrices "
               f"in {time.perf_counter() - t0:.1f}s")
@@ -89,6 +106,8 @@ def main(argv=None):
     add_model_args(p)
     p.add_argument("--kv", default="f32",
                    choices=["f32", "bf16", "int8", "int4"])
+    p.add_argument("--max-len", type=int, default=128,
+                   help="cache slots per lane (prompt + generation)")
     p.add_argument("--requests", type=int, default=8)
     p.add_argument("--new-tokens", type=int, default=16)
     p.add_argument("--batch", type=int, default=4)
@@ -132,7 +151,7 @@ def main(argv=None):
                         "and skip their prefill), and chunked prefill "
                         "(prompts longer than --prefill-len stream in "
                         "prefill_len-sized chunks interleaved with decode)")
-    p.add_argument("--page-size", type=int, default=16,
+    p.add_argument("--page-size", type=int, default=64,
                    help="logical KV slots per page (even; = flash-decode "
                         "kernel block in the paged path)")
     p.add_argument("--n-pages", type=int, default=None,
@@ -194,12 +213,13 @@ def main(argv=None):
     p.add_argument("--profile-steps", type=int, default=20,
                    help="engine steps to capture under --profile-dir")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     params, cfg = build_quantized_model(args)
 
     telemetry = bool(args.telemetry or args.trace or args.profile_dir)
     eng = Engine(params, cfg, ServeConfig(
-        max_len=128, decode_batch=args.batch,
+        max_len=args.max_len, decode_batch=args.batch,
         max_new_tokens=args.new_tokens, kv_dtype=args.kv,
         scheduler=args.scheduler, prefill_len=args.prefill_len,
         temperature=args.temperature, seed=args.seed,

@@ -24,6 +24,7 @@ from pathlib import Path
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import add_model_args, build_quantized_model
 from repro.serve import Engine, Request, ServeConfig, serve_http
 
@@ -66,7 +67,7 @@ def main(argv=None):
     p.add_argument("--prefill-len", type=int, default=32)
     p.add_argument("--fused", default="auto", choices=["auto", "on", "off"])
     p.add_argument("--paged", action="store_true")
-    p.add_argument("--page-size", type=int, default=16)
+    p.add_argument("--page-size", type=int, default=64)
     p.add_argument("--max-step-tokens", type=int, default=None,
                    help="token-budget step scheduler (see ServeConfig)")
     p.add_argument("--spec-k", type=int, default=0,
@@ -94,6 +95,7 @@ def main(argv=None):
                         "completion over real HTTP, validate SSE + "
                         "metrics schema, exit 0/1")
     args = p.parse_args(argv)
+    enable_compile_cache()
     if args.smoke:
         # the smoke validates the full metrics schema, which includes
         # the per-phase step histograms only telemetry records

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.core.api import PTQConfig
 from repro.data import batches, capture_calibration, data_config_for
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import init_lm, lm_loss
 from repro.models.quantize import quantize_model_params, split_qpeft
@@ -54,6 +55,7 @@ def main(argv=None):
                    help="train the full config (needs real hardware)")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full_size:

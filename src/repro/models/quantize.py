@@ -26,7 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.api import CalibStats, LayerReport, PTQConfig, quantize_layer
+from repro.core.api import (CalibStats, LayerReport, PTQConfig, ptq_precision,
+                            quantize_layer)
 from repro.core.qpeft import fixed_gamma_scale, sgp_scale
 from repro.quant import MXIntQuantizer, make_quantizer
 from repro.quant.mxint import pack_codes_4bit
@@ -64,9 +65,10 @@ def _stats_for(stats: Optional[Dict[str, CalibStats]], names: List[str],
 
 
 def _quantize_matrix(name: str, w, stats, cfg: PTQConfig, key,
-                     container: str,
-                     recorder=None) -> Tuple[Dict[str, jax.Array], LayerReport]:
-    dec, rep = quantize_layer(name, w, stats, cfg, key, recorder=recorder)
+                     container: str, recorder=None,
+                     scaling=None) -> Tuple[Dict[str, jax.Array], LayerReport]:
+    dec, rep = quantize_layer(name, w, stats, cfg, key, recorder=recorder,
+                              scaling=scaling)
     qz = MXIntQuantizer(bits=cfg.quantizer.bits,
                         block_size=cfg.quantizer.block_size)
     packed = qz.quantize(dec.q)
@@ -116,13 +118,16 @@ def quantize_model_params(
         lead = w.shape[:-2]
         name = "/".join(names)
         st = _stats_for(stats, names + ["w"], "")
+        # one S for every matrix of a stack: they share the stats
+        with ptq_precision():
+            scaling = st.scaling(cfg.scaling) if st is not None else None
 
         def one(mat, idx):
             counter[0] += 1
             key = jax.random.fold_in(root, counter[0])
             q, rep = _quantize_matrix(f"{name}{list(idx)}", jnp.asarray(mat),
                                       st, cfg, key, container,
-                                      recorder=recorder)
+                                      recorder=recorder, scaling=scaling)
             reports.append(rep)
             if progress:
                 progress(rep)

@@ -175,8 +175,10 @@ class ServeConfig:
     fused: str = "auto"              # Q+LR matmul path: auto | on | off
     # --- paged KV cache (serve.pages / serve.prefix) ---
     paged: bool = False              # block-granular pages + block tables
-    page_size: int = 16              # logical slots per page (even; on real
-    # TPU must meet the Mosaic sublane tile: ≥32, ≥64 for int4)
+    page_size: int = 64              # logical slots per page (even; on real
+    # TPU must meet the Mosaic sublane tile: ≥32, ≥64 for int4 — the
+    # default clears both, so a default paged engine builds on the chip
+    # for every kv_dtype)
     n_pages: Optional[int] = None    # pool size; default sizes for full
     # residency of every lane + one request of prefix-retention headroom
     prefix_cache: bool = True        # radix-tree automatic prefix reuse

@@ -380,19 +380,15 @@ _current_telemetry: Optional["Telemetry"] = None
 def _install_compile_listener() -> None:
     if _listener_state["installed"]:
         return
-    _listener_state["installed"] = True     # even on failure: don't retry
-    try:
-        from jax import monitoring
+    from jax import monitoring
 
-        def _on_duration(event: str, duration: float, **kw) -> None:
-            tel = _current_telemetry
-            if tel is not None and "backend_compile" in event:
-                tel._note_compile_seconds(duration)
+    def _on_duration(event: str, duration: float, **kw) -> None:
+        tel = _current_telemetry
+        if tel is not None and "backend_compile" in event:
+            tel._note_compile_seconds(duration)
 
-        monitoring.register_event_duration_secs_listener(_on_duration)
-    except Exception:
-        pass        # older/newer jax without the hook: first-call timing
-        # (tracked per entry regardless) remains the fallback signal
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    _listener_state["installed"] = True
 
 
 # ==========================================================================
@@ -503,12 +499,9 @@ class Telemetry:
     def step_begin(self) -> None:
         self._step_t0 = time.perf_counter()
         if self.profile_dir and not self._profile_done and not self._profiling:
-            try:
-                import jax
-                jax.profiler.start_trace(self.profile_dir)
-                self._profiling = True
-            except Exception:
-                self._profile_done = True       # don't retry every step
+            import jax
+            jax.profiler.start_trace(self.profile_dir)
+            self._profiling = True
 
     @contextlib.contextmanager
     def phase(self, name: str):
@@ -578,13 +571,10 @@ class Telemetry:
 
     def stop_profiler(self) -> None:
         if self._profiling:
-            try:
-                import jax
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+            import jax
             self._profiling = False
             self._profile_done = True
+            jax.profiler.stop_trace()
 
     # ------------------------------------------------------------------
     def publish(self) -> None:

@@ -162,16 +162,14 @@ def make_compressed_sync(mesh, specs: Any) -> Callable:
     gradients are produced independently and the cross-pod reduction
     should ride DCN compressed.
     """
-    from jax.experimental.shard_map import shard_map
-
     def sync(grads, ef):
         def inner(g, e):
             return ef_compressed_psum(g, e, axis="pod")
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=mesh,
             in_specs=(specs, specs),
             out_specs=(specs, specs),
-            check_rep=False,
+            check_vma=False,
         )(grads, ef)
 
     return sync

@@ -1,17 +1,15 @@
-"""Infrastructure: sharding rules, data determinism, optim, checkpointing."""
+"""Infrastructure: sharding rules, data determinism, optim, checkpointing,
+compile-cache placement."""
 import os
 import tempfile
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
-
-try:  # jax ≥ 0.5
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x
-    AxisType = None
 
 from repro.configs import get_config
 from repro.data import DataConfig, data_config_for, host_batch
@@ -46,8 +44,6 @@ def mesh():
     # an abstract 16×16 mesh built from repeated CPU devices is invalid;
     # use AbstractMesh for pure spec logic
     from jax.sharding import AbstractMesh
-    if AxisType is None:  # jax 0.4.x signature: tuple of (name, size)
-        return AbstractMesh((("data", 16), ("model", 16)))
     return AbstractMesh((16, 16), ("data", "model"),
                         axis_types=(AxisType.Auto,) * 2)
 
@@ -233,3 +229,32 @@ def test_checkpoint_shape_mismatch_raises():
         bad = {"params": {"w": jnp.zeros((2, 2))}, "step": jnp.asarray(0)}
         with pytest.raises(ValueError):
             mgr.restore(bad)
+
+
+# ---------------------------------------------------------------------------
+# Persistent compilation cache placement
+# ---------------------------------------------------------------------------
+@pytest.fixture
+def keep_cache_dir():
+    prev = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_compile_cache_leaves_env_var_to_jax(monkeypatch, keep_cache_dir,
+                                             tmp_path):
+    from repro.launch.compile_cache import enable_compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert enable_compile_cache() is None
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_falls_back_to_fixed_checkout_path(monkeypatch,
+                                                        keep_cache_dir):
+    from repro.launch.compile_cache import enable_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = Path(__file__).resolve().parents[1] / ".jax_cache"
+    assert enable_compile_cache() == want
+    assert enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == str(want)

@@ -19,8 +19,10 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 from repro.configs import get_config
+from repro.core import api
 from repro.core.api import PTQConfig
-from repro.models import init_lm
+from repro.data import capture_calibration, data_config_for
+from repro.models import init_lm, lm_loss
 from repro.models.quantize import quantize_model_params
 from repro.obs import NULL_QUANT_RECORDER, QuantRecorder
 from repro.quant.base import QuantizerConfig
@@ -166,3 +168,67 @@ def test_pipeline_without_recorder_unchanged(quantized):
     _, _, _, recorded_reports = quantized
     assert [(r.name, r.k_star, r.scaled_err) for r in reports] == \
         [(r.name, r.k_star, r.scaled_err) for r in recorded_reports]
+
+
+# ---------------------------------------------------------------------------
+# offline-pass matmul precision
+# ---------------------------------------------------------------------------
+class _PrecisionProbe:
+    """Duck-typed recorder noting the matmul precision each pass ran at."""
+
+    def __init__(self):
+        self.seen = []
+
+    def record_layer(self, *args):
+        self.seen.append(jax.config.jax_default_matmul_precision)
+
+    def attach_container(self, *args):
+        pass
+
+
+def _calibrated_srr_pass(recorder):
+    """qera-exact SRR over the reduced model from fresh calibration."""
+    cfg = get_config("phi3-mini-3.8b").reduced()
+    params = init_lm(jax.random.PRNGKey(0), cfg)
+    ptq = PTQConfig(method="srr", scaling="qera-exact", rank=8,
+                    quantizer=QuantizerConfig(kind="mxint", bits=3,
+                                              block_size=32))
+    stats = capture_calibration(
+        params, cfg, data_config_for(cfg, seq_len=16, global_batch=2,
+                                     seed=0),
+        lambda c, p, b, cc: lm_loss(c, p, b, cc), n_batches=1)
+    return quantize_model_params(params, stats, ptq, recorder=recorder)
+
+
+def test_ptq_pins_highest_precision_and_cpu_containers_unchanged(
+        monkeypatch):
+    """The offline pass runs its f32 matmuls at full precision (the TPU
+    default is bf16 passes). CPU f32 matmuls are full precision either
+    way, so the containers match a pass at the default precision bit
+    for bit."""
+    import numpy as np
+    probe = _PrecisionProbe()
+    pinned, _ = _calibrated_srr_pass(probe)
+    assert probe.seen and set(probe.seen) == {"highest"}
+    monkeypatch.setattr(api, "PTQ_MATMUL_PRECISION", "default")
+    probe = _PrecisionProbe()
+    default, _ = _calibrated_srr_pass(probe)
+    assert set(probe.seen) == {"default"}
+    for a, b in zip(jax.tree_util.tree_leaves(pinned),
+                    jax.tree_util.tree_leaves(default)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_ptq_builds_one_scaling_per_weight_stack(monkeypatch):
+    """The matrices of one stacked weight share their calibration stats,
+    so S (an eigendecomposition) is built once per stack, not once per
+    matrix."""
+    built = []
+    real = api.CalibStats.scaling
+    monkeypatch.setattr(api.CalibStats, "scaling",
+                        lambda self, kind: built.append(kind)
+                        or real(self, kind))
+    _, reports = _calibrated_srr_pass(None)
+    stacks = {r.name.rsplit("[", 1)[0] for r in reports}
+    assert built == ["qera-exact"] * len(stacks)
+    assert len(stacks) < len(reports)

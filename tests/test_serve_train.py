@@ -118,6 +118,17 @@ def test_serve_cli_srr():
     assert "quantized" in r.stdout and "requests" in r.stdout
 
 
+def test_build_quantized_model_cuts_depth_not_width():
+    import argparse
+    import dataclasses
+    from repro.launch.serve import build_quantized_model
+    args = argparse.Namespace(arch="phi3-mini-3.8b", seed=0, method="none",
+                              layers=1)
+    _, cfg = build_quantized_model(args, tag="test")
+    assert cfg == dataclasses.replace(
+        get_config("phi3-mini-3.8b").reduced(), n_layers=1)
+
+
 def test_compressed_psum_subprocess():
     """int8 EF all-reduce over a 'pod' axis (needs >1 device ⇒ subprocess
     with forced host device count)."""
@@ -126,7 +137,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.optim import ef_compressed_psum, init_error_feedback
 from repro.launch.mesh import make_mesh
 mesh = make_mesh((4,), ("pod",))
@@ -135,8 +145,8 @@ ef = jnp.zeros((4, 8))
 def inner(gi, ei):
     s, e2 = ef_compressed_psum(gi[0], ei[0], axis="pod")
     return s[None], e2[None]
-sync = shard_map(inner, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                 out_specs=(P("pod"), P("pod")), check_rep=False)
+sync = jax.shard_map(inner, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                     out_specs=(P("pod"), P("pod")), check_vma=False)
 s, e2 = sync(g, ef)
 # every pod sees the mean (= 2.5); EF residual bounded by one int8 step
 np.testing.assert_allclose(np.asarray(s), 2.5, rtol=0.05)
