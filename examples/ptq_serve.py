@@ -146,7 +146,8 @@ def main():
           f"by the step budget")
     phases = " ".join(
         f"{ph} {pst[f'step_{ph}_seconds']['p50'] * 1e3:.2f}ms"
-        for ph in ("budget", "admission", "prefill", "decode", "transfer"))
+        for ph in ("budget", "admission", "prefill", "decode", "transfer",
+                   "emit"))
     print(f"   step-phase p50: {phases}  "
           f"(ttft p50 {pst['ttft_seconds']['p50'] * 1e3:.0f}ms, "
           f"{pst['compiled_shapes_decode']} decode shape(s) compiled)")

@@ -65,7 +65,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -85,7 +85,7 @@ from repro.serve.scheduler import (ContinuousScheduler, SchedulerStats,
                                    StepBudget)
 from repro.serve.slots import KV_DTYPES, SlotKVCache
 from repro.serve.telemetry import (NULL_TELEMETRY, MetricsRegistry,
-                                   Telemetry, log_buckets)
+                                   Telemetry, install_gc_hook, log_buckets)
 
 
 # --------------------------------------------------------------------------
@@ -344,9 +344,9 @@ class Engine:
         self.ctx = ctx
         # the registry is always live (stats()/metrics()/prometheus()
         # are snapshots of it); the *recorder* — tracing, step-phase
-        # histograms, compile tracking — is the no-op singleton unless
-        # telemetry is on, so the hot loop pays one no-op dispatch per
-        # call site when disabled
+        # histograms, compile tracking — is the recording-free singleton
+        # unless telemetry is on, so the hot loop pays only the profiler
+        # annotation per call site when disabled
         self.registry = MetricsRegistry()
         if sc.telemetry or sc.profile_dir:
             self.tel = Telemetry(registry=self.registry, sync=sc.trace_sync,
@@ -354,6 +354,11 @@ class Engine:
                                  profile_steps=sc.profile_steps)
         else:
             self.tel = NULL_TELEMETRY
+        self._steps = 0                  # serve/step's step_num
+        # collector pauses: process-wide, published as deltas from the
+        # last reset_stats()
+        self._gc = install_gc_hook()
+        self._gc_base = self._gc.totals()
         self.prefill_len = sc.prefill_len or sc.max_len
         if self.prefill_len > sc.max_len:
             raise ValueError(
@@ -1053,12 +1058,21 @@ class Engine:
         """Open this step's token-budget ledger, admit queued requests
         while budget and slots allow, advance in-flight chunked prefills
         (paged; oldest-admitted first, each chunk charged against the
-        budget), then run one decode step over the decoding slots.
-        Returns requests finished now."""
+        budget), then run one decode step over the decoding slots, all
+        inside the ``serve/step`` profiler span. Returns requests
+        finished now."""
         if self.sc.scheduler != "continuous":
             raise RuntimeError("step() needs scheduler='continuous'")
+        self._steps += 1
+        with self.tel.step(self._steps) as span:
+            finished, span.decoding = self._step()
+        self._sanitize()
+        return finished
+
+    def _step(self) -> Tuple[List[Result], int]:
+        """The phases of one step; returns the requests finished and the
+        number of lanes decoded."""
         tel = self.tel
-        tel.step_begin()
         finished: List[Result] = []
         with tel.phase("budget"):
             # charge the lanes already decoding (active minus mid-
@@ -1102,18 +1116,15 @@ class Engine:
         else:
             decoding = self.sched.table.active_slots()
         if not decoding:
-            tel.step_end(0)
-            self._sanitize()
-            return finished
+            return finished, 0
 
+        context = self._context_tokens(decoding)
         k_round = (self._spec_k_for(decoding, budget)
                    if self.sc.speculative else 0)
         if k_round:
             finished.extend(self._spec_round(decoding, k_round))
-            self.sched.note_decode_step(len(decoding))
-            tel.step_end(len(decoding))
-            self._sanitize()
-            return finished
+            self.sched.note_decode_step(len(decoding), context)
+            return finished, len(decoding)
 
         # drift monitor: keep references to the *pre-step* token/cache —
         # the decode jit is functional (nothing donated), so they stay
@@ -1128,24 +1139,30 @@ class Engine:
                 # fence: device time stays in this phase instead of
                 # hiding inside the next host transfer
                 jax.block_until_ready(self._tok)
-        self.sched.note_decode_step(len(decoding))
+        self.sched.note_decode_step(len(decoding), context)
         with tel.phase("transfer"):
             toks = np.asarray(jax.device_get(self._tok))[:, 0]
             lp_host = jax.device_get(lpd) if lpd is not None else None
-        self._host_guard(toks, decoding)
         if drift_in is not None:
             self._observe_drift(drift_in[0], drift_in[1], decoding)
-        for slot in decoding:
-            info = None
-            if lp_host is not None:
-                info = self._lp_entry(self.sched.table.active[slot],
-                                      lp_host[0][slot], lp_host[1][slot],
-                                      lp_host[2][slot])
-            if self._record(slot, toks[slot], info):
-                finished.append(self._finish(slot))
-        tel.step_end(len(decoding))
-        self._sanitize()
-        return finished
+        with tel.phase("emit"):
+            self._host_guard(toks, decoding)
+            for slot in decoding:
+                info = None
+                if lp_host is not None:
+                    info = self._lp_entry(self.sched.table.active[slot],
+                                          lp_host[0][slot], lp_host[1][slot],
+                                          lp_host[2][slot])
+                if self._record(slot, toks[slot], info):
+                    finished.append(self._finish(slot))
+        return finished, len(decoding)
+
+    def _context_tokens(self, decoding: List[int]) -> int:
+        """Tokens the decoding lanes attend over in this step, summed:
+        each lane's prompt (and vision prefix) plus its tokens so far."""
+        active = self.sched.table.active
+        return sum(active[s].prompt_len + self._n_vis + len(active[s].tokens)
+                   for s in decoding)
 
     def _sanitize(self) -> None:
         """Post-step invariant audit (``ServeConfig(sanitize=True)``):
@@ -1299,48 +1316,50 @@ class Engine:
             hosted = {s: (np.asarray(jax.device_get(tv)),
                           jax.device_get(lpd) if lpd is not None else None)
                       for s, (tv, lpd) in verify.items()}
-        for s in decoding:
-            st = states[s]
-            tgt, lp_host = hosted[s]
-            # acceptance: draft j survives while it matches the verify
-            # model's prediction at the same position — the greedy-
-            # speculative rule. An accepted draft IS the verify token
-            # (they compared equal), so emitting tgt[j] below emits the
-            # draft, with the chunk's logprob row for that position.
-            n_acc = 1
-            while n_acc < k and draft_host[s, n_acc - 1] == tgt[n_acc - 1]:
-                n_acc += 1
-            n_accepted += n_acc - 1
-            self._h_accept.observe(n_acc - 1)
-            if n_acc < k:
-                # a rejected draft would be re-proposed verbatim next
-                # round (drafting is deterministic); the correction
-                # must come from a plain step-graph decode
-                self._need_plain = True
-            rec = 0
-            done = False
-            for j in range(n_acc - 1):
-                info = None
-                if lp_host is not None:
-                    info = self._lp_entry(st, lp_host[0][j],
-                                          lp_host[1][j], lp_host[2][j])
-                rec += 1
-                # a stop token inside the accepted window truncates
-                # here — tokens past it are never recorded, matching
-                # non-speculative retirement exactly
-                if self._record(s, int(tgt[j]), info):
-                    done = True
-                    break
-            if rec:
-                tok_host[s, 0] = int(tgt[rec - 1])
-            if done:
-                # _finish re-parks the row at pos 0 — keep the lane out
-                # of the rewind so the reset sticks instead of being
-                # overwritten with the stale frontier
-                results.append(self._finish(s))
-            else:
-                mask[s] = True
-                newpos[s] = p0[s] + rec
+        with tel.phase("emit"):
+            for s in decoding:
+                st = states[s]
+                tgt, lp_host = hosted[s]
+                # acceptance: draft j survives while it matches the verify
+                # model's prediction at the same position — the greedy-
+                # speculative rule. An accepted draft IS the verify token
+                # (they compared equal), so emitting tgt[j] below emits the
+                # draft, with the chunk's logprob row for that position.
+                n_acc = 1
+                while (n_acc < k
+                       and draft_host[s, n_acc - 1] == tgt[n_acc - 1]):
+                    n_acc += 1
+                n_accepted += n_acc - 1
+                self._h_accept.observe(n_acc - 1)
+                if n_acc < k:
+                    # a rejected draft would be re-proposed verbatim next
+                    # round (drafting is deterministic); the correction
+                    # must come from a plain step-graph decode
+                    self._need_plain = True
+                rec = 0
+                done = False
+                for j in range(n_acc - 1):
+                    info = None
+                    if lp_host is not None:
+                        info = self._lp_entry(st, lp_host[0][j],
+                                              lp_host[1][j], lp_host[2][j])
+                    rec += 1
+                    # a stop token inside the accepted window truncates
+                    # here — tokens past it are never recorded, matching
+                    # non-speculative retirement exactly
+                    if self._record(s, int(tgt[j]), info):
+                        done = True
+                        break
+                if rec:
+                    tok_host[s, 0] = int(tgt[rec - 1])
+                if done:
+                    # _finish re-parks the row at pos 0 — keep the lane out
+                    # of the rewind so the reset sticks instead of being
+                    # overwritten with the stale frontier
+                    results.append(self._finish(s))
+                else:
+                    mask[s] = True
+                    newpos[s] = p0[s] + rec
         with tel.phase("verify"):
             self._tok = jnp.asarray(tok_host)
             self.slots.cache = self._rewind(
@@ -1422,6 +1441,16 @@ class Engine:
                   "drift_top1_agree / drift_checks").set(
             round(self._drift_agree / self._drift_checks, 4)
             if self._drift_checks else 1.0)
+        # Python's cycle collector, process-wide (every engine and
+        # thread), since the last reset_stats()
+        n, gen2, pause = (a - b for a, b in zip(self._gc.totals(),
+                                                 self._gc_base))
+        reg.counter("gc_collections",
+                    "cycle-collector passes, process-wide").set(n)
+        reg.counter("gc_collections_gen2",
+                    "full (generation 2) collector passes").set(gen2)
+        reg.counter("gc_pause_seconds",
+                    "seconds inside collector passes").set(pause)
         self.tel.publish()
         return reg
 
@@ -1480,6 +1509,7 @@ class Engine:
         self._drift_agree = 0
         self._drift_nonfinite = 0
         self._guard_oob = 0
+        self._gc_base = self._gc.totals()
         # histogram samples reset even with telemetry off — the
         # acceptance histogram is registry-resident either way
         self.registry.reset_histograms()
@@ -1588,11 +1618,14 @@ class Engine:
                 break
             # a lane is useful only while its (real) request still needs
             # tokens — padding rows and early-stop rows ride along wasted
+            useful = sum(1 for i, r in enumerate(reqs)
+                         if not done[i] and step < self._req_budget(r))
             self._bucket_stats.decode_steps += 1
-            self._bucket_stats.decode_slot_steps += sum(
-                1 for i, r in enumerate(reqs)
-                if not done[i]
-                and step < self._req_budget(r))
+            self._bucket_stats.decode_slot_steps += useful
+            # each lane attends over the prompt and the step + 1 tokens
+            # sampled so far
+            self._bucket_stats.decode_context_tokens += useful * (
+                plen + self._n_vis + step + 1)
             # token index step+1: out[:, step] was token `step`
             (tok, _), cache = self._decode(
                 self.params, tok, cache,

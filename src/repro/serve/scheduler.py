@@ -45,6 +45,8 @@ class SchedulerStats:
     aborted: int = 0                # cancelled via Engine.abort()
     decode_steps: int = 0
     decode_slot_steps: int = 0      # steps × active slots (useful work)
+    decode_context_tokens: int = 0  # per decode step, the tokens the
+    # useful lanes attend over, summed
     budget_deferred_admissions: int = 0  # admissions pushed to a later
     # step because the token budget could not cover their prefill
     budget_capped_chunks: int = 0   # prefill-chunk dispatches skipped
@@ -76,6 +78,9 @@ class SchedulerStats:
         reg.counter("decode_slot_steps",
                     "decode steps x active lanes (useful work)"
                     ).set(self.decode_slot_steps)
+        reg.counter("decode_context_tokens",
+                    "context tokens the useful lanes attend over, summed "
+                    "per decode step").set(self.decode_context_tokens)
         reg.counter("budget_deferred_admissions",
                     "admissions deferred by the token budget"
                     ).set(self.budget_deferred_admissions)
@@ -188,10 +193,13 @@ class ContinuousScheduler:
         self.stats.retired += 1
         return self.table.free(slot)
 
-    def note_decode_step(self, n_useful: Optional[int] = None) -> None:
+    def note_decode_step(self, n_useful: Optional[int] = None,
+                         context: int = 0) -> None:
         """``n_useful`` overrides the useful-lane count for this step —
         the paged engine excludes slots still mid-chunked-prefill (they
-        occupy a lane but ride the decode dispatch masked)."""
+        occupy a lane but ride the decode dispatch masked). ``context``
+        is the tokens those lanes attend over, summed."""
         self.stats.decode_steps += 1
         self.stats.decode_slot_steps += (self.table.n_active
                                          if n_useful is None else n_useful)
+        self.stats.decode_context_tokens += context

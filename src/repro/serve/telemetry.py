@@ -2,7 +2,7 @@
 
 One dependency-free observability layer for the serving stack, replacing
 the ad-hoc ``time.perf_counter()`` calls and per-scheduler ``stats()``
-dicts that grew with PRs 1–5. Three pieces:
+dicts that grew with PRs 1–5. Four pieces:
 
   * **Metrics registry** (:class:`MetricsRegistry`): named counters,
     gauges, and fixed log-spaced-bucket histograms. ``snapshot()``
@@ -16,24 +16,29 @@ dicts that grew with PRs 1–5. Three pieces:
     prefill-chunk[i] → first-token → decode → retired) on its own
     Chrome-trace thread lane, and every engine ``step()`` emits a phase
     breakdown (admission, chunk prefill, decode dispatch, host
-    transfer). Exported as Chrome trace-event JSON (loadable in
-    Perfetto / ``chrome://tracing``) and as a JSONL event stream for
-    programmatic analysis. An opt-in ``sync`` fence
-    (``block_until_ready`` after device dispatch) attributes device
-    time to the phase that launched it instead of hiding it in the
-    next host transfer.
+    transfer, and the emit of the sampled tokens). Exported as Chrome
+    trace-event JSON (loadable in Perfetto / ``chrome://tracing``) and
+    as a JSONL event stream for programmatic analysis. An opt-in
+    ``sync`` fence (``block_until_ready`` after device dispatch)
+    attributes device time to the phase that launched it instead of
+    hiding it in the next host transfer.
   * **JAX-level hooks**: per-entry-point compile tracking — distinct
     dispatched shapes plus real backend-compile seconds via
     ``jax.monitoring`` duration events (the shape-churn recompile
-    detector chunked prefill was built to avoid), and
-    ``jax.profiler.TraceAnnotation`` labels around prefill/decode with
-    optional ``jax.profiler`` trace capture for the first N engine
-    steps (``profile_dir``).
+    detector chunked prefill was built to avoid), and optional
+    ``jax.profiler`` trace capture for the first N engine steps
+    (``profile_dir``).
+  * **Profiler spans**, on with telemetry on or off: every call site
+    enters a ``jax.profiler`` annotation, so any profiler capture shows
+    ``serve/step`` (a step marker carrying ``step_num``), its phases
+    ``serve/phase/<phase>``, the dispatches ``serve/<entry>`` and the
+    cycle collector's pauses ``serve/gc/gen<n>`` on the device trace's
+    clock. With no capture running an annotation costs about a
+    microsecond to enter and exit.
 
-Telemetry is near-zero-cost when disabled: the engine holds a
-:data:`NULL_TELEMETRY` recorder whose methods are no-ops and whose
-context managers are a shared null object — one attribute dispatch per
-call site, no timestamps taken, no events stored.
+When disabled, the engine holds a :data:`NULL_TELEMETRY` recorder that
+records nothing — no timestamps taken, no events or histograms stored —
+and whose context managers are the profiler annotations alone.
 
 Also here: the shared **interpolating percentile** helper (numpy
 "linear" method). The previous hand-rolled index math
@@ -46,10 +51,13 @@ from __future__ import annotations
 
 import bisect
 import contextlib
+import gc
 import json
 import math
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 
 # ==========================================================================
@@ -392,12 +400,82 @@ def _install_compile_listener() -> None:
 
 
 # ==========================================================================
-# Telemetry facade
+# Profiler spans: the names every engine call site enters, recorder or not
 # ==========================================================================
 STEP_PHASES = ("budget", "admission", "prefill", "decode", "verify",
-               "transfer")
+               "transfer", "emit")
+STEP_SPAN = "serve/step"
+PHASE_SPANS = {p: f"serve/phase/{p}" for p in STEP_PHASES}
 
 
+class StepSpan:
+    """``serve/step`` around one ``Engine.step``: a profiler step
+    marker carrying ``step_num``. The engine sets ``decoding`` (lanes
+    decoded this step) before the span closes."""
+
+    __slots__ = ("_ann", "decoding")
+
+    def __init__(self, num: int):
+        self._ann = StepTraceAnnotation(STEP_SPAN, step_num=num)
+        self.decoding = 0
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        return False
+
+
+class _GcPauses:
+    """``gc.callbacks`` hook: each cycle-collector pass becomes a
+    ``serve/gc/gen<n>`` profiler span and is counted, process-wide."""
+
+    def __init__(self):
+        self.collections = 0
+        self.gen2 = 0
+        self.pause_s = 0.0
+        self._span = None
+        self._t0 = 0.0
+
+    def __call__(self, phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            self._span = TraceAnnotation(f"serve/gc/gen{info['generation']}")
+            self._span.__enter__()
+            self._t0 = time.perf_counter()
+        elif self._span is not None:
+            self.pause_s += time.perf_counter() - self._t0
+            self._span.__exit__(None, None, None)
+            self._span = None
+            self.collections += 1
+            self.gen2 += info["generation"] == 2
+
+    def totals(self) -> Tuple[int, int, float]:
+        return self.collections, self.gen2, self.pause_s
+
+
+_GC = _GcPauses()
+
+
+def install_gc_hook() -> _GcPauses:
+    """Install the collector hook once per process (every ``Engine``
+    calls this; a second call is a no-op) and return it."""
+    if _GC not in gc.callbacks:
+        gc.callbacks.append(_GC)
+    return _GC
+
+
+def remove_gc_hook() -> None:
+    """Take the collector hook out of ``gc.callbacks`` (its totals
+    stay); the next ``Engine`` puts it back."""
+    if _GC in gc.callbacks:
+        gc.callbacks.remove(_GC)
+
+
+# ==========================================================================
+# Telemetry facade
+# ==========================================================================
 class Telemetry:
     """Live recorder the engine drives; owns the tracer and publishes
     request/step histograms plus compile stats into the (shared)
@@ -419,7 +497,6 @@ class Telemetry:
         self._profiling = False
         self._profile_done = False
         self._step_idx = 0
-        self._step_t0: Optional[float] = None
         self._requests: Dict[int, Dict[str, float]] = {}
         # entry point → dispatch/compile accounting
         self.compiles: Dict[str, Dict[str, Any]] = {}
@@ -496,36 +573,43 @@ class Telemetry:
     # ------------------------------------------------------------------
     # Engine step phases
     # ------------------------------------------------------------------
-    def step_begin(self) -> None:
-        self._step_t0 = time.perf_counter()
+    @contextlib.contextmanager
+    def step(self, num: int):
+        """One engine step: the ``serve/step`` span, the step histogram
+        and the trace's step event. A capture armed by ``profile_dir``
+        starts before the span opens and stops after it closes, so the
+        captured steps are whole."""
         if self.profile_dir and not self._profile_done and not self._profiling:
             import jax
             jax.profiler.start_trace(self.profile_dir)
             self._profiling = True
-
-    @contextlib.contextmanager
-    def phase(self, name: str):
         t0 = time.perf_counter()
+        span = StepSpan(num)
         try:
-            yield
+            with span:
+                yield span
         finally:
-            t1 = time.perf_counter()
-            self._h_phase[name].observe(t1 - t0)
-            self.tracer.complete(name, self.tracer.us(t0), (t1 - t0) * 1e6,
-                                 PID_ENGINE, 0)
-
-    def step_end(self, n_decoding: int) -> None:
-        t0, self._step_t0 = self._step_t0, None
-        if t0 is not None:
             t1 = time.perf_counter()
             self._h_step.observe(t1 - t0)
             self.tracer.complete("step", self.tracer.us(t0),
                                  (t1 - t0) * 1e6, PID_ENGINE, 0,
                                  args={"step": self._step_idx,
-                                       "decoding": n_decoding})
-        self._step_idx += 1
-        if self._profiling and self._step_idx >= self.profile_steps:
-            self.stop_profiler()
+                                       "decoding": span.decoding})
+            self._step_idx += 1
+            if self._profiling and self._step_idx >= self.profile_steps:
+                self.stop_profiler()
+
+    @contextlib.contextmanager
+    def phase(self, name: str):
+        t0 = time.perf_counter()
+        try:
+            with TraceAnnotation(PHASE_SPANS[name]):
+                yield
+        finally:
+            t1 = time.perf_counter()
+            self._h_phase[name].observe(t1 - t0)
+            self.tracer.complete(name, self.tracer.us(t0), (t1 - t0) * 1e6,
+                                 PID_ENGINE, 0)
 
     # ------------------------------------------------------------------
     # JAX hooks: compile tracking + profiler annotations
@@ -536,33 +620,27 @@ class Telemetry:
         ``shape_key`` signatures per entry (a growing set = the shape
         churn chunked prefill exists to avoid), attributes
         ``jax.monitoring`` backend-compile seconds to this entry while
-        the dispatch is live, times first-seen-signature calls as the
-        fallback compile signal, and labels the region for the JAX
-        profiler timeline."""
+        the dispatch is live, and labels the region ``serve/<name>``
+        for the JAX profiler timeline."""
         global _current_telemetry
         info = self.compiles.setdefault(
             name, {"shapes": set(), "compiles": 0, "calls": 0,
-                   "compile_seconds": 0.0, "first_call_seconds": 0.0})
+                   "compile_seconds": 0.0})
         info["calls"] += 1
         first = shape_key not in info["shapes"]
         prev, _current_telemetry = _current_telemetry, self
         self._entry_name = name
-        t0 = time.perf_counter()
         try:
-            import jax
-            with jax.profiler.TraceAnnotation(f"serve/{name}"):
+            with TraceAnnotation(f"serve/{name}"):
                 yield
         finally:
             _current_telemetry = prev
             if first:
-                dt = time.perf_counter() - t0
                 info["shapes"].add(shape_key)
                 info["compiles"] += 1
-                info["first_call_seconds"] += dt
                 self.tracer.instant(f"compile:{name}", self.tracer.now_us(),
                                     PID_ENGINE, 0,
-                                    args={"shape": str(shape_key),
-                                          "first_call_s": round(dt, 6)})
+                                    args={"shape": str(shape_key)})
 
     def _note_compile_seconds(self, seconds: float) -> None:
         info = self.compiles.get(getattr(self, "_entry_name", None))
@@ -590,10 +668,6 @@ class Telemetry:
             reg.gauge(f"compile_seconds_{name}",
                       f"jax backend-compile seconds attributed to {name}"
                       ).set(round(info["compile_seconds"], 6))
-            reg.gauge(f"first_call_seconds_{name}",
-                      f"wall seconds of first-seen-shape {name} calls "
-                      f"(compile fallback signal)"
-                      ).set(round(info["first_call_seconds"], 6))
 
     def reset_run(self) -> None:
         """Start a fresh measured run: drop trace events, open request
@@ -602,7 +676,6 @@ class Telemetry:
         self.tracer.reset()
         self._requests.clear()
         self._step_idx = 0
-        self._step_t0 = None
         self.registry.reset_histograms()
 
     def close(self) -> None:
@@ -611,22 +684,12 @@ class Telemetry:
 
 # ==========================================================================
 # Disabled recorder: every engine call site dispatches through one of
-# these no-ops — a single attribute lookup + call, no timestamps, no
-# allocation. Shared singletons.
+# these — no timestamps, no events, no histograms; its context managers
+# are the profiler annotations alone. Shared singleton.
 # ==========================================================================
-class _NullContext:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_CTX = _NullContext()
-
-
 class NullTelemetry:
-    """No-op recorder; ``Engine`` holds this when telemetry is off."""
+    """Recording-free recorder; ``Engine`` holds this when telemetry is
+    off. Steps, phases and dispatches still enter their profiler spans."""
 
     enabled = False
     sync = False
@@ -648,17 +711,14 @@ class NullTelemetry:
     def request_retired(self, uid, n_tokens, ttft_s, latency_s, decode_s):
         pass
 
-    def step_begin(self):
-        pass
+    def step(self, num):
+        return StepSpan(num)
 
     def phase(self, name):
-        return _NULL_CTX
+        return TraceAnnotation(PHASE_SPANS[name])
 
     def entry(self, name, shape_key):
-        return _NULL_CTX
-
-    def step_end(self, n_decoding):
-        pass
+        return TraceAnnotation(f"serve/{name}")
 
     def publish(self):
         pass

@@ -8,6 +8,7 @@ well-formedness, telemetry-on vs -off token parity on the paged int4
 fused engine, the zero-budget ``Result`` timing regression, compile
 tracking, and the checked-in metrics schema via the CI validator.
 """
+import gc
 import json
 import subprocess
 import sys
@@ -21,9 +22,11 @@ from repro.configs import get_config
 from repro.models import init_lm
 from repro.serve import (Engine, MetricsRegistry, Request, ServeConfig,
                          latency_summary, percentile)
-from repro.serve.telemetry import Histogram, Telemetry, log_buckets
+from repro.serve.telemetry import (Histogram, Telemetry, install_gc_hook,
+                                   log_buckets, remove_gc_hook)
 
 REPO = Path(__file__).resolve().parents[1]
+GC_KEYS = ("gc_collections", "gc_collections_gen2", "gc_pause_seconds")
 
 
 # ---------------------------------------------------------------------------
@@ -196,7 +199,9 @@ def test_telemetry_is_behaviorally_invisible(paged_runs):
 
 def test_unified_snapshot_preserves_legacy_keys(tiny, paged_runs):
     """Telemetry must only *add* series: every key the disabled engine
-    reports appears with the identical value in the enabled snapshot."""
+    reports appears with the identical value in the enabled snapshot
+    (the collector counters are process-wide, so only their presence
+    is compared)."""
     cfg, params = tiny
     base = dict(kv_dtype="int4", fused="on", paged=True, page_size=8)
     st_off = _engine(cfg, params, **base)
@@ -204,12 +209,15 @@ def test_unified_snapshot_preserves_legacy_keys(tiny, paged_runs):
     st_off = st_off.stats()
     st_on = paged_runs[0].stats()
     for key, val in st_off.items():
+        if key in GC_KEYS:
+            assert key in st_on
+            continue
         assert st_on[key] == val, f"legacy key {key} drifted"
     for key in ("step_seconds", "ttft_seconds", "itl_seconds",
                 "prefill_chunk_seconds", "request_latency_seconds"):
         assert key not in st_off           # histograms are telemetry-only
         assert st_on[key]["count"] > 0, f"{key} never observed"
-    for ph in ("admission", "prefill", "decode", "transfer"):
+    for ph in ("admission", "prefill", "decode", "transfer", "emit"):
         assert st_on[f"step_{ph}_seconds"]["count"] > 0
 
 
@@ -228,11 +236,10 @@ def test_bucketed_stats_emit_common_keys(tiny):
 def test_compile_tracking(paged_runs):
     st = paged_runs[0].stats()
     # one decode shape (the whole point of lockstep decode), one chunk
-    # shape; first-call wall time recorded as the compile fallback
+    # shape
     assert st["compiled_shapes_decode"] == 1
     assert st["compiled_shapes_prefill_chunk"] == 1
     assert st["dispatches_decode"] > st["compiled_shapes_decode"]
-    assert st["first_call_seconds_decode"] > 0
     assert st["compile_seconds_decode"] >= 0
 
 
@@ -330,3 +337,108 @@ def test_null_telemetry_interface_is_complete():
         pass
     with NULL_TELEMETRY.entry("decode", (1, 2)):
         pass
+    with NULL_TELEMETRY.step(7) as span:
+        span.decoding = 2
+
+
+# ---------------------------------------------------------------------------
+# Profiler spans, collector pauses and the decode context count
+# ---------------------------------------------------------------------------
+def _host_spans(trace_dir):
+    """``serve/`` spans of a profile directory, as (name, start, end)."""
+    from jax.profiler import ProfileData
+    pb = sorted(Path(trace_dir).glob("plugins/profile/*/*.xplane.pb"))[-1]
+    pd = ProfileData.from_file(str(pb))
+    return sorted(((e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for p in pd.planes if p.name.startswith("/host:")
+                   for ln in p.lines for e in ln.events
+                   if e.name.startswith("serve/")), key=lambda e: e[1])
+
+
+@pytest.mark.parametrize("telemetry", [False, True])
+def test_forced_collection_is_a_span_and_counted(tiny, tmp_path, telemetry):
+    """A full collection inside a step's emit phase shows as a
+    ``serve/gc/gen2`` span nested in ``serve/phase/emit`` and moves the
+    always-live collector counters, with telemetry off or on."""
+    cfg, params = tiny
+    eng = _engine(cfg, params, paged=True, page_size=8, telemetry=telemetry)
+    guard = eng._host_guard
+
+    def collecting_guard(toks, decoding):
+        gc.collect()
+        guard(toks, decoding)
+
+    eng._host_guard = collecting_guard
+    try:
+        for r in _reqs(cfg, 2):
+            eng.submit(r)
+        st0 = eng.stats()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            eng.drain()
+        finally:
+            jax.profiler.stop_trace()
+        st1 = eng.stats()
+        assert st1["gc_collections_gen2"] > st0["gc_collections_gen2"]
+        assert st1["gc_collections"] >= st1["gc_collections_gen2"]
+        assert st1["gc_pause_seconds"] > st0["gc_pause_seconds"]
+        spans = _host_spans(tmp_path)
+        emits = [s for s in spans if s[0] == "serve/phase/emit"]
+        gen2 = [s for s in spans if s[0] == "serve/gc/gen2"]
+        assert emits and len(gen2) >= len(emits)
+        for _, a, b in emits:
+            assert any(a <= s and e <= b for _, s, e in gen2)
+        assert "gc_collections_gen2" in eng.prometheus()
+    finally:
+        remove_gc_hook()
+
+
+def test_gc_hook_is_installed_once_and_removable(tiny):
+    cfg, params = tiny
+    try:
+        _engine(cfg, params)
+        eng = _engine(cfg, params)
+        hook = install_gc_hook()
+        assert sum(cb is hook for cb in gc.callbacks) == 1
+        remove_gc_hook()
+        assert hook not in gc.callbacks
+        n = eng.stats()["gc_collections"]
+        gc.collect()
+        assert eng.stats()["gc_collections"] == n
+        eng.reset_stats()
+        assert eng.stats()["gc_collections"] == 0
+    finally:
+        remove_gc_hook()
+
+
+def test_disabled_recorder_only_annotates(tiny):
+    """Telemetry off: the call sites enter profiler annotations and
+    record nothing — no events, no step or phase histograms."""
+    from jax.profiler import TraceAnnotation
+    from repro.serve.telemetry import NULL_TELEMETRY, STEP_PHASES
+    cfg, params = tiny
+    eng = _engine(cfg, params, paged=True, page_size=8)
+    assert eng.tel is NULL_TELEMETRY and NULL_TELEMETRY.tracer is None
+    for ph in STEP_PHASES:
+        assert isinstance(NULL_TELEMETRY.phase(ph), TraceAnnotation)
+    assert isinstance(NULL_TELEMETRY.entry("decode", (2, 1)),
+                      TraceAnnotation)
+    eng.generate(_reqs(cfg, 3))
+    st = eng.stats()
+    assert not any(k.startswith("step_") for k in st)
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_decode_context_tokens(tiny, paged):
+    """One request of prompt P and budget N decodes N - 1 steps, the
+    j-th over P + j tokens: (N - 1) P + N (N - 1) / 2 in all."""
+    cfg, params = tiny
+    kw = dict(paged=True, page_size=8) if paged else {}
+    eng = _engine(cfg, params, **kw)
+    req = _reqs(cfg, 1, budget=5)[0]
+    plen, n = len(req.prompt), 5
+    res = eng.generate([req])
+    assert len(res[0].tokens) == n
+    st = eng.stats()
+    assert st["decode_steps"] == n - 1
+    assert st["decode_context_tokens"] == (n - 1) * plen + n * (n - 1) // 2
