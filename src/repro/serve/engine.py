@@ -11,9 +11,12 @@ scale, under two schedulers:
     the live cache while the other slots keep decoding. Per-request
     ``max_new_tokens`` / EOS retire a slot the moment its request
     finishes, and the next queued request takes the lane on the same
-    step. Exactly **two compiled shapes** total — one (1, prefill_len)
-    prefill, one (slots, 1) decode — regardless of the prompt-length mix
-    (prompts are right-padded and masked, never re-bucketed).
+    step. One (slots, 1) decode shape plus the prefill shapes —
+    one (1, prefill_len) prefill, or under the paged cache the chunk
+    ladder (the chunk width and its quarters down to one page; a
+    prompt's final chunk takes the narrowest that holds it) —
+    regardless of the prompt-length mix (prompts are right-padded and
+    masked, never re-bucketed).
   * ``bucketed`` (baseline): the old dry-run-grade scheduler — requests
     grouped by identical prompt length, each bucket padded to
     ``decode_batch`` and decoded to its slowest member. Kept for A/B
@@ -156,6 +159,21 @@ def release_absorbed_params() -> None:
     _absorb_cache = None
 
 
+def chunk_ladder(chunk_len: int, page_size: int) -> Tuple[int, ...]:
+    """Compiled widths a paged engine dispatches prefill chunks at,
+    widest first: ``chunk_len // 4**i`` for as long as the width is at
+    least one page and a whole number of pages (``chunk_len`` itself is
+    always the first). A prompt's final chunk runs at the narrowest
+    width that holds its remaining tokens, so a short question over a
+    cached document does not pay for a full-width chunk."""
+    widths = [chunk_len]
+    while True:
+        w = chunk_len // 4 ** len(widths)
+        if w < page_size or w % page_size:
+            return tuple(widths)
+        widths.append(w)
+
+
 @dataclasses.dataclass
 class ServeConfig:
     max_len: int = 512               # cache slots (prompt + generation)
@@ -170,7 +188,8 @@ class ServeConfig:
     compute_dtype: str = "f32"
     scheduler: str = "continuous"    # continuous | bucketed
     prefill_len: Optional[int] = None  # compiled prompt pad length; under
-    # --paged this is the *chunk* width, no longer a prompt-length cap
+    # --paged this is the *chunk* width, no longer a prompt-length cap,
+    # and the top width of the chunk ladder a final chunk narrows along
     seed: int = 0                    # sampling stream for submit()/step()
     fused: str = "auto"              # Q+LR matmul path: auto | on | off
     # --- paged KV cache (serve.pages / serve.prefix) ---
@@ -551,11 +570,18 @@ class Engine:
                                strict=jax.default_backend() == "tpu")
         self._chunk_len = self.prefill_len + self.prefill_len % 2 \
             if sc.paged else self.prefill_len
+        # compiled chunk widths, widest first: every chunk but a prompt's
+        # last runs at _chunk_len (so chunk starts never move); the last
+        # runs at the narrowest width that holds what is left of it
+        self._chunk_widths = (chunk_ladder(self._chunk_len, self.page_size)
+                              if sc.paged else (self._chunk_len,))
 
         # token-budget config: the unit of prefill work is one compiled-
-        # width dispatch (a "partial" chunk still computes the full
-        # width), and an admission whose prefill completes immediately
-        # also joins decode the same step (+1)
+        # width dispatch, charged at the width dispatched (a partial
+        # chunk computes its whole width), and an admission whose
+        # prefill completes immediately also joins decode the same step
+        # (+1). The construction check below takes the full width: an
+        # engine must be able to admit a full-width chunk
         self._step_unit = self._chunk_len if sc.paged else self.prefill_len
         if sc.max_step_tokens is not None:
             if sc.scheduler != "continuous":
@@ -666,6 +692,7 @@ class Engine:
         self._prefill_jobs: Dict[int, "_PrefillJob"] = {}
         self._prefill_chunks = 0
         self._prefill_tokens_computed = 0
+        self._prefill_chunk_tokens_dispatched = 0
         self._prompt_tokens_total = 0
         self._prefix_hit_tokens = 0
         for slot in range(sc.decode_batch):
@@ -829,9 +856,10 @@ class Engine:
         if not self.sched.queue or self.sched.table.n_free == 0:
             return None
         # the admission's first chunk runs this step (prepaid below);
-        # cheap gate before touching the prefix tree — the exact cost
-        # (is the first chunk final?) is re-checked after matching
-        if not budget.can(self._chunk_len):
+        # cheap gate before touching the prefix tree, at the narrowest
+        # width — the exact cost (is the first chunk final, and how
+        # wide?) is re-checked after matching
+        if not budget.can(self._chunk_widths[-1]):
             self.sched.stats.budget_deferred_admissions += 1
             return None
         nxt = self.sched.next_admission()
@@ -851,11 +879,7 @@ class Engine:
             matched = self.prefix.match(req.prompt,
                                         max_blocks=(eff - 1) // ps)
         m_tok = len(matched) * ps
-        # exact budget cost: one compiled-width chunk, +1 decode lane if
-        # that chunk already completes the prompt (the slot joins decode
-        # this very step)
-        cost = self._chunk_len + (1 if eff - m_tok <= self._chunk_len
-                                  else 0)
+        cost = self._chunk_cost(eff - m_tok)
         need = -(-(eff + max(state.budget, 0)) // ps) - len(matched)
         fresh = self.pool.alloc(need) if budget.can(cost) else None
         if fresh is None:
@@ -887,6 +911,19 @@ class Engine:
         self._prefix_hit_tokens += m_tok
         return []
 
+    def _chunk_width(self, left: int) -> int:
+        """Compiled width of the next chunk of a prompt with ``left``
+        tokens still to prefill: the full chunk width, or for the final
+        chunk the narrowest ladder width that holds it."""
+        return next((w for w in reversed(self._chunk_widths) if w >= left),
+                    self._chunk_len)
+
+    def _chunk_cost(self, left: int) -> int:
+        """Token-budget charge of that chunk: its compiled width, +1
+        decode lane when it completes the prompt (the slot joins decode
+        this very step)."""
+        return self._chunk_width(left) + (left <= self._chunk_len)
+
     def _advance_prefill(self, slot: int) -> List[Result]:
         """Run one prefill chunk for a mid-admission slot; on the final
         chunk, sample the first token and (maybe) retire."""
@@ -895,12 +932,13 @@ class Engine:
         c = self._chunk_len
         start = job.next
         length = min(c, eff - start)
-        tokens = np.zeros((1, c), np.int32)
+        w = self._chunk_width(eff - start)
+        tokens = np.zeros((1, w), np.int32)
         tokens[0, :length] = job.req.prompt[start:start + length]
         final = start + length >= eff
         want_lp = final and job.state.sampling.logprobs is not None
         t0 = time.perf_counter()
-        with self.tel.entry("prefill_chunk", (1, c)):
+        with self.tel.entry("prefill_chunk", (1, w)):
             # non-final chunks discard the sampled token — the lane
             # arrays still ride along so the compiled shape is uniform
             (tok, lpd), self.slots.cache = self._chunk(
@@ -917,6 +955,7 @@ class Engine:
         job.next = start + length
         self._prefill_chunks += 1
         self._prefill_tokens_computed += length
+        self._prefill_chunk_tokens_dispatched += w
         if not final:
             return []
         del self._prefill_jobs[slot]
@@ -1094,9 +1133,9 @@ class Engine:
         if self.sc.paged:
             # one chunk per prefilling slot per step — oldest admission
             # first, so FIFO order also bounds prefill wait — with each
-            # dispatch charged at its compiled width (+1 when the final
-            # chunk promotes the slot to decode this step); jobs the
-            # budget cannot cover resume on a later step
+            # dispatch charged at the width it is dispatched at (+1 when
+            # the final chunk promotes the slot to decode this step);
+            # jobs the budget cannot cover resume on a later step
             with tel.phase("prefill"):
                 jobs = sorted(self._prefill_jobs.items(),
                               key=lambda kv: kv[1].state.t_admit)
@@ -1104,9 +1143,8 @@ class Engine:
                     if job.prepaid:
                         job.prepaid = False
                     else:
-                        eff = job.state.prompt_len
-                        cost = self._chunk_len + (
-                            1 if eff - job.next <= self._chunk_len else 0)
+                        cost = self._chunk_cost(job.state.prompt_len
+                                                - job.next)
                         if not budget.try_take(cost):
                             self.sched.stats.budget_capped_chunks += 1
                             continue
@@ -1403,6 +1441,11 @@ class Engine:
             reg.counter("prefill_tokens_computed",
                         "prompt tokens actually prefilled"
                         ).set(self._prefill_tokens_computed)
+            reg.counter("prefill_chunk_tokens_dispatched",
+                        "compiled widths of the prefill chunks "
+                        "dispatched, summed (pad share: 1 - "
+                        "prefill_tokens_computed / this)"
+                        ).set(self._prefill_chunk_tokens_dispatched)
             reg.counter("prompt_tokens_total", "prompt tokens submitted"
                         ).set(total)
             reg.counter("prefix_hit_tokens",
@@ -1500,6 +1543,7 @@ class Engine:
                 self.prefix.reset_stats()
             self._prefill_chunks = 0
             self._prefill_tokens_computed = 0
+            self._prefill_chunk_tokens_dispatched = 0
             self._prompt_tokens_total = 0
             self._prefix_hit_tokens = 0
         self._spec_rounds = 0
@@ -1518,11 +1562,11 @@ class Engine:
         self.tel.reset_run()
 
     def warmup(self) -> None:
-        """Trigger the compiles (prefill + decode; + draft/verify/rewind
-        under speculative mode — the dummy's budget covers one full-k
-        round) with a dummy request so steady-state timing excludes
-        compilation. Counters are reset afterwards — the dummy never
-        shows in stats()."""
+        """Trigger the compiles (prefill, or every width of the paged
+        chunk ladder, + decode; + draft/verify/rewind under speculative
+        mode — the dummy's budget covers one full-k round) with a dummy
+        request so steady-state timing excludes compilation. Counters
+        are reset afterwards — the dummy never shows in stats()."""
         if self.sc.scheduler != "continuous":
             return
         # speculative: spec_k + 1 covers one full-k round plus a
@@ -1533,6 +1577,20 @@ class Engine:
         self.submit(dummy)
         while self.sched.has_work:
             self.step()
+        if self.sc.paged:
+            # the dummy's one-token prompt ran the narrowest chunk width;
+            # run every wider one on the idle state (jit is pure: the
+            # result is dropped), so no admission waits on a compile
+            lanes = (jnp.zeros((1,), jnp.float32),
+                     jnp.ones((1,), jnp.float32),
+                     jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
+                     jnp.zeros((1,), jnp.int32))
+            for w in self._chunk_widths[:-1]:
+                with self.tel.entry("prefill_chunk", (1, w)):
+                    jax.block_until_ready(self._chunk(
+                        self.params, jnp.zeros((1, w), jnp.int32),
+                        self.slots.cache, jnp.int32(0), jnp.int32(0),
+                        jnp.int32(1), lanes, False)[0][0])
         if self.sc.speculative:
             # the dummy run only exercises k = spec_k; the clamped
             # variants (a lane close to its token budget shrinks the

@@ -36,21 +36,23 @@ def _reqs(cfg, n, base_len=5, budget=None, params=None):
 # ---------------------------------------------------------------------------
 def _drain_counting(eng):
     """Step the engine to empty, asserting the per-step charge invariant
-    from the stats deltas: prefill dispatches at compiled width + decode
-    lanes never exceed max_step_tokens."""
+    from the stats deltas: prefill dispatches at the widths dispatched
+    (paged: the summed chunk widths of ``prefill_chunk_tokens_dispatched``;
+    unpaged: one compiled prefill per admission) + decode lanes never
+    exceed max_step_tokens."""
     limit = eng.sc.max_step_tokens
     unit = eng._step_unit
+
+    def dispatched():
+        return eng.stats()["prefill_chunk_tokens_dispatched"] \
+            if eng.sc.paged else eng.sched.stats.admitted * unit
+
     results = []
     while eng.sched.has_work:
-        s0 = eng.sched.stats
-        chunks0 = getattr(eng, "_prefill_chunks", 0)
-        dec0, adm0 = s0.decode_slot_steps, s0.admitted
+        dec0, pre0 = eng.sched.stats.decode_slot_steps, dispatched()
         results.extend(eng.step())
-        s1 = eng.sched.stats
-        chunks1 = getattr(eng, "_prefill_chunks", 0)
-        spent = (chunks1 - chunks0) * unit if eng.sc.paged \
-            else (s1.admitted - adm0) * unit
-        spent += s1.decode_slot_steps - dec0
+        spent = dispatched() - pre0
+        spent += eng.sched.stats.decode_slot_steps - dec0
         assert spent <= limit, f"step spent {spent} > budget {limit}"
     results.sort(key=lambda r: r.uid)
     return results

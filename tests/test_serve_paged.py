@@ -14,6 +14,7 @@ import pytest
 from repro.configs import get_config
 from repro.models import init_lm
 from repro.serve import Engine, Request, ServeConfig
+from repro.serve.engine import chunk_ladder
 
 
 @pytest.fixture(scope="module")
@@ -44,21 +45,39 @@ def _same_tokens(a, b, msg=""):
         np.testing.assert_array_equal(ra.tokens, rb.tokens, err_msg=msg)
 
 
+def _record_chunks(eng):
+    """Wrap the engine's chunk program: returns the list each dispatch
+    appends its (start, compiled width) to."""
+    seen, chunk = [], eng._chunk
+
+    def recorded(params, tokens, cache, row, start, *rest):
+        seen.append((int(start), tokens.shape[1]))
+        return chunk(params, tokens, cache, row, start, *rest)
+
+    eng._chunk = recorded
+    return seen
+
+
 # ---------------------------------------------------------------------------
 # Token parity: paged vs unpaged (acceptance criterion)
 # ---------------------------------------------------------------------------
+@pytest.mark.parametrize("prefill_len", [16, 64])
 @pytest.mark.parametrize("kv_dtype", ["f32", "bf16", "int8", "int4"])
-def test_paged_matches_unpaged_greedy(tiny, kv_dtype):
+def test_paged_matches_unpaged_greedy(tiny, kv_dtype, prefill_len):
     """Paging the cache must be behaviorally invisible: identical greedy
     tokens for every KV container, with slot reuse (more requests than
-    slots) in the mix."""
+    slots) in the mix. At prefill_len 64 the ladder is (64, 16), so the
+    5–7-token prompts run at the narrower width."""
     cfg, params = tiny
     budget = {i: 3 + (i % 4) for i in range(6)}
-    res_u = _engine(cfg, params, kv_dtype=kv_dtype).generate(
+    res_u = _engine(cfg, params, kv_dtype=kv_dtype,
+                    prefill_len=prefill_len).generate(
         _reqs(cfg, 6, budget=budget))
-    res_p = _engine(cfg, params, kv_dtype=kv_dtype, paged=True,
-                    page_size=8).generate(_reqs(cfg, 6, budget=budget))
+    eng = _engine(cfg, params, kv_dtype=kv_dtype, paged=True, page_size=8,
+                  prefill_len=prefill_len)
+    res_p = eng.generate(_reqs(cfg, 6, budget=budget))
     _same_tokens(res_u, res_p, f"paged diverged at kv={kv_dtype}")
+    assert eng.stats()["prefill_chunk_tokens_dispatched"] == 6 * 16
 
 
 @pytest.mark.parametrize("fused", ["off", "auto", "on"])
@@ -99,8 +118,13 @@ def test_paged_streaming_submit_step_drain(tiny):
 # ---------------------------------------------------------------------------
 # Chunked prefill (prompt > prefill_len)
 # ---------------------------------------------------------------------------
-def test_chunked_prefill_long_prompt_exact(tiny):
-    """A 40-token prompt through a 16-wide chunk shape: three chunks,
+@pytest.mark.parametrize("prefill_len,chunks", [
+    (16, [(0, 16), (16, 16), (32, 16)]),      # ladder (16,): 16 // 4 < page
+    (32, [(0, 32), (32, 8)]),                 # ladder (32, 8)
+])
+def test_chunked_prefill_long_prompt_exact(tiny, prefill_len, chunks):
+    """A 40-token prompt through a narrower chunk shape: chunks start
+    every prefill_len tokens at full width and only the last narrows,
     tokens identical to the bucketed scheduler's native-length prefill
     (the unpaged continuous engine rejects this prompt outright). f32
     KV: chunked attention over stored context is then mathematically
@@ -110,16 +134,20 @@ def test_chunked_prefill_long_prompt_exact(tiny):
     prompt = rng.integers(0, cfg.vocab, size=40).astype(np.int32)
     mk = lambda: [Request(uid=0, prompt=prompt.copy(),  # noqa: E731
                           max_new_tokens=6)]
-    kw = dict(max_len=96, decode_batch=2, prefill_len=16, kv_dtype="f32")
+    kw = dict(max_len=96, decode_batch=2, prefill_len=prefill_len,
+              kv_dtype="f32")
     with pytest.raises(ValueError, match="prefill"):
         _engine(cfg, params, **kw).submit(mk()[0])
     res_b = _engine(cfg, params, scheduler="bucketed", **kw).generate(mk())
     eng = _engine(cfg, params, paged=True, page_size=8, **kw)
+    seen = _record_chunks(eng)
     res_p = eng.generate(mk())
     _same_tokens(res_b, res_p, "chunked prefill diverged from bucketed")
+    assert seen == chunks
     st = eng.stats()
-    assert st["prefill_chunks"] == 3          # ceil(40 / 16)
+    assert st["prefill_chunks"] == len(chunks)  # ceil(40 / prefill_len)
     assert st["prefill_tokens_computed"] == 40
+    assert st["prefill_chunk_tokens_dispatched"] == sum(w for _, w in chunks)
 
 
 @pytest.mark.parametrize("kv_dtype", ["f32", "int4"])
@@ -208,6 +236,106 @@ def test_chunked_prefill_interleaves_with_decode(tiny):
         [Request(uid=1, prompt=long_prompt.copy(), max_new_tokens=4)])
     np.testing.assert_array_equal(done[0].tokens, ref_s[0].tokens)
     np.testing.assert_array_equal(done[1].tokens, ref_l[0].tokens)
+
+
+# ---------------------------------------------------------------------------
+# Chunk ladder: a prompt's final chunk at the narrowest width that holds it
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("chunk_len,page_size,ladder", [
+    (4096, 64, (4096, 1024, 256, 64)),
+    (64, 8, (64, 16)),
+    (128, 8, (128, 32, 8)),
+    (16, 8, (16,)),                           # the quarter is under a page
+    (96, 16, (96,)),                          # the quarter is 1.5 pages
+    (192, 16, (192, 48)),                     # 12 is under a page
+])
+def test_chunk_ladder(chunk_len, page_size, ladder):
+    assert chunk_ladder(chunk_len, page_size) == ladder
+
+
+def test_chunk_ladder_follows_the_engine_geometry(tiny):
+    cfg, params = tiny
+    eng = _engine(cfg, params, paged=True, page_size=8, max_len=128,
+                  prefill_len=64)
+    assert eng._chunk_widths == (64, 16)
+    assert eng._chunk_len == 64
+    assert _engine(cfg, params)._chunk_widths == (16,)   # unpaged
+
+
+def test_prefix_hit_final_chunk_narrows(tiny):
+    """A question over a cached document: 40 document tokens (5 pages)
+    hit the prefix cache, the 6 fresh tokens run in one 16-wide chunk
+    instead of a 64-wide one, and the greedy tokens are those of the
+    unpaged engine (f32 KV: the stored context is exact)."""
+    cfg, params = tiny
+    rng = np.random.default_rng(21)
+    doc = rng.integers(0, cfg.vocab, size=40).astype(np.int32)
+    question = rng.integers(0, cfg.vocab, size=6).astype(np.int32)
+    prompt = np.concatenate([doc, question])
+    kw = dict(max_len=128, decode_batch=2, prefill_len=64, kv_dtype="f32",
+              max_new_tokens=6)
+    eng = _engine(cfg, params, paged=True, page_size=8, **kw)
+    eng.generate([Request(uid=0, prompt=doc.copy(), max_new_tokens=1)])
+    seen = _record_chunks(eng)
+    res_p = eng.generate([Request(uid=1, prompt=prompt.copy())])
+    st = eng.stats()
+    assert st["prefix_hit_tokens"] == 40
+    assert seen == [(40, 16)]
+    assert st["prefill_tokens_computed"] == 6
+    assert st["prefill_chunk_tokens_dispatched"] == 16
+    res_u = _engine(cfg, params, **kw).generate(
+        [Request(uid=1, prompt=prompt.copy())])
+    _same_tokens(res_u, res_p, "narrowed final chunk diverged from unpaged")
+
+
+def test_narrow_chunk_budget_admits_more(tiny):
+    """The token budget charges the width dispatched: a budget of 64 + 1
+    has no room for a 64-wide chunk (+1 lane) beside a decoding lane,
+    but admits a short prompt's 16-wide chunk there."""
+    cfg, params = tiny
+    eng = _engine(cfg, params, paged=True, page_size=8, max_len=128,
+                  prefill_len=64, decode_batch=2, max_step_tokens=64 + 1,
+                  kv_dtype="f32")
+    reqs = _reqs(cfg, 2, base_len=5)
+    eng.submit(reqs[0])
+    eng.step()                                # uid 0 decoding
+    eng.submit(reqs[1])
+    d0 = eng.stats()["prefill_chunk_tokens_dispatched"]
+    eng.step()                                # 1 lane + 16 + 1 <= 65
+    assert eng.stats()["prefill_chunk_tokens_dispatched"] - d0 == 16
+    assert eng.sched.table.n_active == 2
+    assert eng.stats()["budget_deferred_admissions"] == 0
+
+
+def test_warmup_compiles_every_ladder_width(tiny):
+    """warmup() compiles and runs every ladder width, so serving prompts
+    that take each width (with and without a prefix hit) compiles
+    nothing afterwards."""
+    from jax import monitoring
+    cfg, params = tiny
+    eng = _engine(cfg, params, paged=True, page_size=8, max_len=128,
+                  prefill_len=64, decode_batch=2, max_new_tokens=3,
+                  telemetry=True)
+    compiles = []
+    monitoring.register_event_duration_secs_listener(
+        lambda ev, d, **kw: compiles.append(ev)
+        if "backend_compile" in ev else None)
+    rng = np.random.default_rng(5)
+    doc = rng.integers(0, cfg.vocab, size=40).astype(np.int32)
+    prompts = [rng.integers(0, cfg.vocab, size=n).astype(np.int32)
+               for n in (5, 30, 70)] + [np.concatenate([doc, doc[:3]])]
+    eng.warmup()
+    assert eng.stats()["compiled_shapes_prefill_chunk"] == 2
+    n0 = len(compiles)
+    eng.generate([Request(uid=0, prompt=doc.copy())])
+    eng.generate([Request(uid=i + 1, prompt=p)
+                  for i, p in enumerate(prompts)])
+    st = eng.stats()
+    assert st["prefix_hit_tokens"] == 40
+    # 5 → 16; 30 → 64; 70 → 64 + 16; 3 fresh after the hit → 16
+    assert st["prefill_chunk_tokens_dispatched"] == 16 + 64 + 80 + 16
+    assert len(compiles) == n0, compiles[n0:]
+    assert st["compiled_shapes_prefill_chunk"] == 2
 
 
 # ---------------------------------------------------------------------------
