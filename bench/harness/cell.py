@@ -17,11 +17,9 @@ from harness.traffic import Traffic
 from harness.weights import leaf_specs, make_params
 from harness.window import GRACE_S, Window, percentile, token_gaps, tokens_in
 
-MODEL_KEYS = {"n_layers": "n_layers", "d_model": "d_model",
-              "n_heads": "n_heads", "n_kv_heads": "n_kv_heads",
-              "head_dim": "head_dim_", "d_ff": "d_ff", "vocab": "vocab",
-              "qkv_bias": "qkv_bias", "rope": "rope_kind",
-              "rope_theta": "rope_theta"}
+# engine counters read around the window
+COUNTERS = ("prefix_hit_tokens", "prompt_tokens_total", "prefill_chunks",
+            "decode_steps", "decode_slot_steps", "decode_context_tokens")
 
 
 def log(msg: str) -> None:
@@ -48,13 +46,14 @@ class Cell:
 
     # ------------------------------------------------------------ set-up
     def build(self):
+        # the configuration's architecture, before anything is built
+        self.arch = arch = registry.reference(self.conf)
         import jax
         from repro.configs import get_config
         from repro.launch.compile_cache import enable_compile_cache
         from repro.models import init_lm
         from repro.models.quantize import quantized_abstract
         from repro.serve import Engine, ServeConfig
-        from reference import leaf_specs as ref_specs
 
         enable_compile_cache()
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
@@ -63,7 +62,7 @@ class Cell:
 
         model, wconf = self.conf["model"], self.conf["weights"]
         cfg = get_config(self.conf["arch"])
-        for k, attr in MODEL_KEYS.items():
+        for k, attr in arch.MODEL_KEYS.items():
             if getattr(cfg, attr) != model[k]:
                 raise ValueError(f"{self.conf['arch']}: program has {attr}="
                                  f"{getattr(cfg, attr)}, configuration file "
@@ -73,16 +72,17 @@ class Cell:
             rank=wconf["rank"], block_size=wconf["block"],
             container=wconf["container"])
         got = {p: (s, d) for p, (s, d) in leaf_specs(abstract).items()}
-        want = ref_specs(model, wconf)
+        want = arch.leaf_specs(model, wconf)
         if got != want:
             diff = sorted(set(got.items()) ^ set(want.items()))[:6]
             raise ValueError(f"served tree differs from the reference's "
                              f"layout: {diff}")
-        params = make_params(self.seed, abstract)
+        params = make_params(self.seed, abstract, arch.BRANCH_OUT)
         jax.block_until_ready(params)
         self.part("weights_on_device")
 
-        self.work = step_work(params, model, self.conf["serve"]["kv_dtype"])
+        self.work = step_work(params, model, self.conf["serve"]["kv_dtype"],
+                              arch)
         sc = ServeConfig(**dict(self.conf["serve"], paged=True,
                                 **self.overrides))
         eng = Engine(params, cfg, sc)
@@ -153,8 +153,7 @@ class Cell:
             f"setup_s {setup_s:.3f}")
         device = self.device_stats()
         counters = {k: stats1.get(k, 0) - stats0.get(k, 0)
-                    for k in ("prefix_hit_tokens", "prompt_tokens_total",
-                              "prefill_chunks", "decode_steps")}
+                    for k in COUNTERS}
         log(f"engine counters over the window: {counters}")
         extra = {}
         if self.traced:
@@ -213,7 +212,7 @@ class Cell:
     def layer_metrics(self, tracer, counters, due, closed):
         run = layers.TracedRun(
             trace=tracer.reduce(), window_s=tracer.t_off - tracer.t_on,
-            decode_calls=tracer.calls, work=self.work, model=self.model,
+            work=self.work, model=self.model,
             lanes=self.sc.decode_batch,
             peaks=registry.peaks(self.dev.device_kind), counters=counters,
             gen_lags_s=[] if closed else [f.sent - f.due for f in due])
@@ -226,8 +225,8 @@ class Cell:
         idle = T.gaps(ops)
         dev = {"busy_s": T.busy_s(ops), "window_s": run.window_s}
         log(f"trace: {len(ops)} device ops, {len(run.modules)} programs, "
-            f"{len(run.trace.host)} host spans, {len(run.decode_calls)} "
-            f"decode calls; busy {dev['busy_s']:.6f} of "
+            f"{len(run.trace.host)} host spans, {counters['decode_steps']} "
+            f"decode steps; busy {dev['busy_s']:.6f} of "
             f"{dev['window_s']:.6f} s")
         extra = {"breakdown": {"device_ops": T.top_ops(ops),
                                "idle_gaps": T.idle_by_host(idle,
@@ -257,12 +256,12 @@ class Cell:
         reference's best at its position, over every sampled token: the
         widest gap alone does not separate the float32 program from its
         bfloat16 control on the chip (PERF.md, section 4)."""
-        from reference import Reference, served_gaps
+        from reference.common import served_gaps
         limits = registry.checks(self.cell["name"])
         t = time.perf_counter()
         mean_gap = widest = float("inf")
         if sample:
-            ref = Reference(self.model, self.conf["weights"])
+            ref = self.arch.Reference(self.model, self.conf["weights"])
             per = served_gaps(ref, self.seed, sample,
                               batch=self.mix["check_requests"],
                               groups=self.mix["prefixes"]["count"])
@@ -285,11 +284,10 @@ class Cell:
 
 class Tracer:
     """Profile the window; annotate the engine's steps and dispatches from
-    here, and note each decode dispatch's live lanes and context."""
+    here."""
 
     def __init__(self, eng):
         import jax
-        self.eng, self.calls, self.on = eng, [], False
         ann = jax.profiler.TraceAnnotation
         step, decode, chunk = eng.step, eng._decode, eng._chunk
 
@@ -298,13 +296,6 @@ class Tracer:
                 return step()
 
         def traced_decode(*a):
-            if self.on:
-                st = eng.sched.table.active
-                live = [s for s in st if s not in eng._prefill_jobs]
-                self.calls.append(layers.DecodeCall(
-                    live=len(live), ctx=sum(st[s].prompt_len
-                                            + len(st[s].tokens)
-                                            for s in live)))
             with ann("decode_dispatch"):
                 return decode(*a)
 
@@ -320,11 +311,9 @@ class Tracer:
         self.dir = tempfile.mkdtemp(prefix="bench_trace_")
         jax.profiler.start_trace(self.dir)
         self.t_on = time.perf_counter()
-        self.on = True
 
     def stop(self) -> None:
         import jax
-        self.on = False
         self.t_off = time.perf_counter()
         jax.profiler.stop_trace()
 

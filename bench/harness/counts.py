@@ -1,9 +1,11 @@
 """The operations and bytes a decode step needs, from the served shapes.
 
 Everything is counted from the parameter tree the engine serves (its
-leaves' shapes and dtypes, read at set-up) and from each step's live
-context, never from constants. These are the work of the algorithm, not
-of a particular kernel: a kernel that reads more than it needs shows as a
+leaves' shapes and dtypes, read at set-up), from the configuration's
+architecture module (the KV cache's bytes and the attention's
+multiply-adds per token of context) and from each step's live context,
+never from constants. These are the work of the algorithm, not of a
+particular kernel: a kernel that reads more than it needs shows as a
 lower roofline share.
 """
 from __future__ import annotations
@@ -30,26 +32,30 @@ class StepWork:
     qlr_weight_bytes: int     # Q codes + block scales + L + R, every layer
     qlr_macs_per_row: int     # multiply-adds of the Q+LR matmuls per token
     qlr_io_per_row: int       # activation bytes in + out per token (f32)
+    qlr_calls_per_step: int   # Q+LR matmuls per step: containers x layers
     dense_weight_bytes: int   # LM head, norms, biases (read every step)
     head_macs_per_row: int
-    kv_bytes_per_token: int   # one token's K and V over all layers
+    kv_bytes_per_token: int   # one token's cached state over all layers
     attn_macs_per_ctx: int    # multiply-adds per (query, key) over layers
 
 
-def kv_bytes_per_token(model: Dict, kv_dtype: str) -> int:
-    """K and V of one token, all layers, in the cache's storage type
-    (int4: half a byte per value plus an f32 scale per head)."""
-    vals = 2 * model["n_layers"] * model["n_kv_heads"] * model["head_dim"]
-    scales = 2 * model["n_layers"] * model["n_kv_heads"]
+def cache_bytes(values: int, scales: int, kv_dtype: str) -> int:
+    """Bytes of ``values`` cached numbers in the cache's storage type,
+    with ``scales`` f32 scales where the type is quantized (int4: half a
+    byte per value)."""
     if kv_dtype == "int4":
-        return vals // 2 + 4 * scales
+        return values // 2 + 4 * scales
     if kv_dtype == "int8":
-        return vals + 4 * scales
-    return vals * {"bf16": 2, "f32": 4}[kv_dtype]
+        return values + 4 * scales
+    return values * {"bf16": 2, "f32": 4}[kv_dtype]
 
 
-def step_work(params, model: Dict, kv_dtype: str) -> StepWork:
-    qlr_bytes = macs = io = dense = 0
+def leaf_work(params) -> Dict[str, int]:
+    """The weights' part of :class:`StepWork`, from a walk over the
+    served tree: every ``Q + LR`` container (a parent holding ``codes`` or
+    ``packed``) is a matmul per layer of its stack; every other leaf but
+    the embedding is read whole each step."""
+    qlr_bytes = macs = io = calls = dense = 0
     flat = jax.tree_util.tree_flatten_with_path(params)[0]
     by_parent: Dict[str, Dict[str, object]] = {}
     for p, x in flat:
@@ -65,18 +71,29 @@ def step_work(params, model: Dict, kv_dtype: str) -> StepWork:
             m, rank, n = l.shape[-2], l.shape[-1], r.shape[-1]
             macs += layers * (m * n + rank * (m + n))
             io += layers * 4 * (m + n)
+            calls += layers
             if "b" in leaves:
                 dense += nbytes(leaves["b"])
         elif parent != "embed":
             dense += sum(nbytes(v) for v in leaves.values())
-    d, v = model["d_model"], model["vocab"]
-    return StepWork(
-        qlr_weight_bytes=qlr_bytes, qlr_macs_per_row=macs,
-        qlr_io_per_row=io, dense_weight_bytes=dense,
-        head_macs_per_row=d * v,
-        kv_bytes_per_token=kv_bytes_per_token(model, kv_dtype),
-        attn_macs_per_ctx=2 * model["n_layers"] * model["n_heads"]
-        * model["head_dim"])
+    return dict(qlr_weight_bytes=qlr_bytes, qlr_macs_per_row=macs,
+                qlr_io_per_row=io, qlr_calls_per_step=calls,
+                dense_weight_bytes=dense,
+                head_macs_per_row=int(np.prod(by_parent["lm_head"]["w"]
+                                              .shape)))
+
+
+def step_work(params, model: Dict, kv_dtype: str, arch) -> StepWork:
+    """The work of one decode step of ``params``; ``arch`` is the
+    configuration's architecture module, whose own ``step_work`` is used
+    where it has one."""
+    own = getattr(arch, "step_work", None)
+    if own is not None:
+        return own(params, model, kv_dtype)
+    return StepWork(**leaf_work(params),
+                    kv_bytes_per_token=arch.kv_bytes_per_token(model,
+                                                               kv_dtype),
+                    attn_macs_per_ctx=arch.attn_macs_per_ctx(model))
 
 
 def roofline_s(flops: float, nbytes: float, peak_flops: float,

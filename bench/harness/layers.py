@@ -10,8 +10,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional
 
-import numpy as np
-
 from harness import trace as T
 from harness.counts import StepWork, roofline_s
 
@@ -29,25 +27,24 @@ def qlr_decode_kernel(lanes: int) -> str:
             r".*custom-call")
 
 
-QLR_PER_LAYER = 7                       # wq wk wv wo up gate down
-
-
 @dataclasses.dataclass
 class DecodeCall:
-    live: int          # lanes decoding
-    ctx: int           # their live context summed (tokens each attends)
+    live: float        # lanes decoding
+    ctx: float         # their live context summed (tokens each attends)
 
 
 @dataclasses.dataclass
 class TracedRun:
     trace: T.Trace
     window_s: float
-    decode_calls: List[DecodeCall]
     work: StepWork
     model: Dict
     lanes: int                       # compiled decode batch
     peaks: Dict
-    counters: Dict[str, float]       # engine counters over the window
+    # the engine's counters over the window; the decode step's work reads
+    # decode_steps, decode_slot_steps (live lanes summed over the steps)
+    # and decode_context_tokens (their context summed likewise)
+    counters: Dict[str, float]
     gen_lags_s: List[float]          # open loop: sent minus due
 
     @property
@@ -60,10 +57,12 @@ class TracedRun:
 
 
 def _mean_call(run: TracedRun) -> Optional[DecodeCall]:
-    if not run.decode_calls:
+    """The mean decode step of the window, from the engine's counters."""
+    steps = run.counters.get("decode_steps", 0)
+    if not steps:
         return None
-    return DecodeCall(live=float(np.mean([c.live for c in run.decode_calls])),
-                      ctx=float(np.mean([c.ctx for c in run.decode_calls])))
+    return DecodeCall(live=run.counters["decode_slot_steps"] / steps,
+                      ctx=run.counters["decode_context_tokens"] / steps)
 
 
 def kernel_share(run: TracedRun, pattern: str, flops_per_call: float,
@@ -82,7 +81,8 @@ def kernel_share(run: TracedRun, pattern: str, flops_per_call: float,
 def qlr_roofline(run: TracedRun) -> Optional[float]:
     """The decode Q+LR kernel: one call per quantized matrix per layer
     per decode step, over the compiled decode batch (bound: memory)."""
-    w, per_step = run.work, QLR_PER_LAYER * run.model["n_layers"]
+    w = run.work
+    per_step = w.qlr_calls_per_step
     flops = 2.0 * run.lanes * w.qlr_macs_per_row / per_step
     nbytes = (w.qlr_weight_bytes + run.lanes * w.qlr_io_per_row) / per_step
     return kernel_share(run, qlr_decode_kernel(run.lanes), flops, nbytes)

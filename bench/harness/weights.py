@@ -9,7 +9,8 @@ what an SRR pass would have put in them, so the benchmark fills them from
     its lone -4, so that the weight has mean zero);
   * ``scale``  f32 powers of two, one per 32-row block, set so that the
     dequantized weight has a standard deviation near 1/sqrt(rows)
-    (``wo`` and ``down`` also divided by sqrt(2 x layers));
+    (the last matrix of each residual branch also divided by
+    sqrt(2 x layers));
   * ``l``, ``r`` f32 rank-r factors whose product adds about a quarter of
     the backbone's output scale (the path a dropped correction would
     show in);
@@ -19,7 +20,10 @@ what an SRR pass would have put in them, so the benchmark fills them from
 
 A stacked (scanned) leaf draws layer ``i`` from ``fold_in(key, i)`` at the
 per-layer shape, so :mod:`reference` regenerates any one layer alone and
-gets the same numbers the served model holds.
+gets the same numbers the served model holds. Which matrices end a
+residual branch is the architecture's (its module's ``BRANCH_OUT``: the
+parent names of those matrices), so every function that draws a leaf
+takes it.
 """
 from __future__ import annotations
 
@@ -58,18 +62,20 @@ def _std_rows(rows: int) -> float:
     return 1.0 / math.sqrt(rows)
 
 
-def _branch_gain(parent: str, depth: int) -> float:
+def _branch_gain(parent: str, depth: int,
+                 branch_out: Tuple[str, ...]) -> float:
     """Output scale of a matrix: the last matrix of each residual branch
-    (``wo``, ``down``) shrinks as 1/sqrt(2 x depth), as in the repo's own
-    initializer, so the residual stream grows no faster than a sum of
-    unit steps."""
-    return 1.0 / math.sqrt(2 * depth) if parent in ("wo", "down") else 1.0
+    (its parent named in ``branch_out``) shrinks as 1/sqrt(2 x depth), as
+    in the repo's own initializer, so the residual stream grows no faster
+    than a sum of unit steps."""
+    return 1.0 / math.sqrt(2 * depth) if parent in branch_out else 1.0
 
 
 def make_leaf(key: jax.Array, path: str, shape: Tuple[int, ...],
-              dtype, depth: int = 1) -> jax.Array:
+              dtype, depth: int, branch_out: Tuple[str, ...]) -> jax.Array:
     """One per-layer leaf (no stack dim) from its key and path; ``depth``
-    is the number of layers in the stack."""
+    is the number of layers in the stack, ``branch_out`` the parent names
+    of the matrices that end a residual branch."""
     name = path.rsplit(".", 1)[-1]
     parent = path.rsplit(".", 2)[-2] if path.count(".") >= 1 else ""
     f32 = jnp.float32
@@ -78,7 +84,7 @@ def make_leaf(key: jax.Array, path: str, shape: Tuple[int, ...],
         return v.astype(jnp.int8)
     if name == "scale":
         rows = shape[-2] * MX_BLOCK
-        std = _std_rows(rows) * _branch_gain(parent, depth)
+        std = _std_rows(rows) * _branch_gain(parent, depth, branch_out)
         e0 = math.floor(math.log2(std / CODE_STD))
         bump = jax.random.bernoulli(key, 0.5, shape).astype(f32)
         return jnp.exp2(e0 + bump).astype(dtype)
@@ -88,7 +94,7 @@ def make_leaf(key: jax.Array, path: str, shape: Tuple[int, ...],
     if name == "r":
         rank = shape[-2]
         return (jax.random.normal(key, shape, f32)
-                * (LR_OUT_SCALE * _branch_gain(parent, depth)
+                * (LR_OUT_SCALE * _branch_gain(parent, depth, branch_out)
                    / math.sqrt(rank))).astype(dtype)
     if name == "gscale":
         return jnp.ones(shape, dtype)
@@ -105,11 +111,12 @@ def make_leaf(key: jax.Array, path: str, shape: Tuple[int, ...],
 
 
 def make_stacked_leaf(key: jax.Array, path: str, shape: Tuple[int, ...],
-                      dtype) -> jax.Array:
+                      dtype, branch_out: Tuple[str, ...]) -> jax.Array:
     """A scanned leaf (leading dim = layer): layer i from fold_in(key, i)."""
     n = shape[0]
     keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(jnp.arange(n))
-    return jax.vmap(lambda k: make_leaf(k, path, shape[1:], dtype, n))(keys)
+    return jax.vmap(lambda k: make_leaf(k, path, shape[1:], dtype, n,
+                                        branch_out))(keys)
 
 
 def path_str(path) -> str:
@@ -126,9 +133,10 @@ def leaf_specs(tree) -> Dict[str, Tuple[Tuple[int, ...], str]]:
             for p, x in flat}
 
 
-def make_params(seed: int, abstract):
+def make_params(seed: int, abstract, branch_out: Tuple[str, ...]):
     """Fill ``abstract`` (a ShapeDtypeStruct tree) from the seed in one
-    jitted call: every leaf is produced on the device in its served type."""
+    jitted call: every leaf is produced on the device in its served type.
+    ``branch_out``: the architecture module's ``BRANCH_OUT``."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(abstract)
     paths = [path_str(p) for p, _ in flat]
     specs = [(tuple(x.shape), x.dtype) for _, x in flat]
@@ -138,9 +146,10 @@ def make_params(seed: int, abstract):
         for path, (shape, dtype) in zip(paths, specs):
             k = leaf_key(root, path)
             if path.startswith(STACKED_PREFIX):
-                out.append(make_stacked_leaf(k, path, shape, dtype))
+                out.append(make_stacked_leaf(k, path, shape, dtype,
+                                             branch_out))
             else:
-                out.append(make_leaf(k, path, shape, dtype))
+                out.append(make_leaf(k, path, shape, dtype, 1, branch_out))
         return out
 
     leaves = jax.jit(build)(root_key(seed))

@@ -85,9 +85,14 @@ class Window:
         return f
 
     def take(self) -> Req:
-        r = self.traffic.requests[self.next_req]
+        """The next request. A closed loop whose clients outrun the pool
+        goes round its body again (the warm-start requests stay first)."""
+        reqs, i = self.traffic.requests, self.next_req
+        if i >= len(reqs):
+            warm = self.traffic.spec["clients"]
+            i = warm + (i - warm) % (len(reqs) - warm)
         self.next_req += 1
-        return r
+        return reqs[i]
 
     def step_until(self, ready, limit_s: float) -> bool:
         """Step the engine until ``ready()`` or ``limit_s`` passes."""
@@ -116,7 +121,10 @@ class Window:
 
     def run_closed(self, seconds: float) -> tuple:
         """Each client sends its next request as soon as its answer is
-        complete, between steps."""
+        complete, between steps. Once ``seconds`` have passed nothing more
+        is sent; the window closes when the step in flight then has
+        returned, so every step started in it counts, with all its time,
+        and the rate does not move in whole steps."""
         t0 = time.perf_counter()
         t1 = t0 + seconds
         while time.perf_counter() < t1:
@@ -125,7 +133,7 @@ class Window:
             for _ in done:
                 self.send(self.take(), now)
             self.eng.step()
-        return t0, t1
+        return t0, time.perf_counter()
 
     # ------------------------------------------------------------ open loop
     def run_open(self, seconds: float, preroll_s: float) -> tuple:

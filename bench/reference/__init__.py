@@ -1,4 +1,4 @@
-"""Plain float32 reference of the served models (see forward.py)."""
-from reference.forward import Reference, leaf_specs, served_gaps
-
-__all__ = ["Reference", "leaf_specs", "served_gaps"]
+"""The plain float32 reference: one architecture module per block family
+(``<name>.py``, named by a configuration's ``"reference"`` key and loaded
+by :func:`harness.registry.reference`), and what they share
+(:mod:`reference.common`)."""

@@ -1,44 +1,38 @@
-"""Plain float32 forward of the served model, written from its equations.
+"""What every architecture module of the reference shares.
 
-It reads nothing of the program: the model's sizes come from the
-configuration file, and the weights are regenerated layer by layer from
-the seed (:mod:`harness.weights`), dequantized as ``W = Q·s + L diag(g) R``
-into dense float32 matrices, under ``jax.default_matmul_precision
-("highest")``.
+An architecture module (``reference/<name>.py``, named by a
+configuration's ``"reference"`` key; its contract is in
+:mod:`harness.registry`) writes its block from these pieces:
 
-The block (pre-norm decoder):
+  * the equations any decoder uses: :func:`rmsnorm`, :func:`rope`;
+  * the int4 KV cache as the serving engine stores it: :func:`kv_store`,
+    per (position, head), symmetric, ``scale = max|x| / 7``,
+    ``codes = clip(round(x / scale), -7, 7)``;
+  * :func:`attention`, which reads stored and fresh keys as the engine's
+    chunked prefill does. A prompt position reads its own chunk's keys
+    fresh and everything before the chunk from storage; a decoded
+    position reads all of its context, itself included, from storage.
+    Prompt positions below the request's prefix-cache hit ``m`` were
+    prefilled by an earlier request in chunks that started at 0; the rest
+    in chunks starting at ``m`` (:func:`chunk_starts`);
+  * the served ``Q + LR`` containers: :func:`qlr_specs` (their layout)
+    and :func:`qlr_weight` (layer ``i`` regenerated from the seed and
+    dequantized as ``W = Q·s + L diag(g) R`` into a dense float32
+    matrix, :mod:`harness.weights`);
+  * :class:`Stack`, the run over a batch of requests: the first ``m``
+    tokens of a request are a sequence of their own, the same for every
+    request that shares them, so each shared prefix is computed once,
+    layer by layer, and every request's own tokens (the rest of its
+    prompt, then its served tokens) attend to that prefix's stored state,
+    then to their own; :func:`pack` lays the requests out for it;
+  * :func:`served_gaps`, the number the check compares.
 
-    h  = rmsnorm(x) * g1                     (eps 1e-6)
-    q, k, v = h Wq + bq, h Wk + bk, h Wv + bv
-    q, k = rope(q), rope(k)                  adjacent pairs (2i, 2i+1);
-                                             "full": every dim, "half":
-                                             the first hd/2 (ChatGLM 2D)
-    x  = x + attn(q, k, v) Wo                causal, GQA: head h reads
-                                             kv head h // (H / KV)
-    h2 = rmsnorm(x) * g2
-    x  = x + (silu(h2 Wgate) * (h2 Wup)) Wdown
-    logits = (rmsnorm(x) * gf) Whead
-
-The configuration serves with an int4 KV cache, so attention reads keys
-and values as they are stored: per (position, kv head), symmetric,
-``scale = max|x| / 7``, ``codes = clip(round(x / scale), -7, 7)``. Which
-reads are stored and which are fresh follows the serving engine's
-chunked prefill: a prompt position reads its own chunk's keys fresh and
-everything before the chunk from storage; a decoded position reads all of
-its context, itself included, from storage. Prompt positions below the
-request's prefix-cache hit ``m`` were prefilled by an earlier request in
-chunks that started at 0; the rest in chunks starting at ``m``.
-
-So the first ``m`` tokens of a request are a sequence of their own, the
-same for every request that shares them: the reference computes each
-shared prefix once, layer by layer, and every request's own tokens
-(the rest of its prompt, then its served tokens) attend to that
-prefix's stored keys and values, then to their own.
+It reads nothing of the program.
 """
 from __future__ import annotations
 
 import math
-from typing import Dict, List, Sequence
+from typing import Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,75 +45,46 @@ NORM_EPS = 1e-6
 ROW_BLOCK = 256                 # query rows per attention block
 PAD_TO = 1024                   # sequences pad to a multiple of this
 SERVED_BLOCK = 64               # served tokens per request pad to this
+QLR_KINDS = ("codes", "scale", "l", "r", "gscale")
 
 
 # ---------------------------------------------------------------- layout
-def linear_dims(model: Dict) -> Dict[str, tuple]:
-    d, h, kv, hd, ff = (model["d_model"], model["n_heads"],
-                        model["n_kv_heads"], model["head_dim"], model["d_ff"])
-    return {"mixer.wq": (d, h * hd), "mixer.wk": (d, kv * hd),
-            "mixer.wv": (d, kv * hd), "mixer.wo": (h * hd, d),
-            "mlp.up": (d, ff), "mlp.gate": (d, ff), "mlp.down": (ff, d)}
-
-
-def leaf_specs(model: Dict, weights: Dict) -> Dict[str, tuple]:
-    """{path: (shape, dtype)} of the served parameter tree, derived from
-    the configuration alone (the harness checks the program's tree
-    against it before serving)."""
-    L, d, v = model["n_layers"], model["d_model"], model["vocab"]
+def qlr_specs(base: str, m: int, n: int, layers: int,
+              weights: Dict) -> Dict[str, tuple]:
+    """{path: (shape, dtype)} of one stacked ``Q + LR`` container of an
+    (m, n) matrix under ``base`` (a path ending in '.')."""
     rank, blk = weights["rank"], weights["block"]
-    out = {"embed.w": ((v, d), "float32"),
-           "final_norm.g": ((d,), "float32"),
-           "lm_head.w": ((d, v), "float32"),
-           "groups.p0.norm1.g": ((L, d), "float32"),
-           "groups.p0.norm2.g": ((L, d), "float32")}
-    for name, (m, n) in linear_dims(model).items():
-        mp = -(-m // blk) * blk
-        r = min(rank, min(m, n) // 2) if min(m, n) < 2 * rank else rank
-        base = f"groups.p0.{name}."
-        out[base + "codes"] = ((L, mp, n), "int8")
-        out[base + "scale"] = ((L, mp // blk, n), "float32")
-        out[base + "l"] = ((L, m, r), "float32")
-        out[base + "r"] = ((L, r, n), "float32")
-        out[base + "gscale"] = ((L, r), "float32")
-        if model["qkv_bias"] and name in ("mixer.wq", "mixer.wk",
-                                          "mixer.wv"):
-            out[base + "b"] = ((L, n), "float32")
-    return out
+    mp = -(-m // blk) * blk
+    r = min(rank, min(m, n) // 2) if min(m, n) < 2 * rank else rank
+    return {base + "codes": ((layers, mp, n), "int8"),
+            base + "scale": ((layers, mp // blk, n), "float32"),
+            base + "l": ((layers, m, r), "float32"),
+            base + "r": ((layers, r, n), "float32"),
+            base + "gscale": ((layers, r), "float32")}
 
 
 # --------------------------------------------------------------- weights
-def _layer_leaf(root, path, shape, dtype, i, depth):
+def layer_leaf(root, path: str, specs: Dict[str, tuple], i,
+               branch_out: Tuple[str, ...]):
+    """Layer ``i`` (traced index) of the stacked leaf at ``path``, as
+    :func:`harness.weights.make_params` drew it."""
+    shape, dt = specs[path]
     k = jax.random.fold_in(W.leaf_key(root, path), i)
-    return W.make_leaf(k, path, shape, dtype, depth)
+    return W.make_leaf(k, path, shape[1:], jnp.dtype(dt), shape[0],
+                       branch_out)
 
 
-def _layer_weights(root, i, specs: Dict[str, tuple], model: Dict):
-    """Dense float32 weights of layer ``i`` (traced index)."""
-    out = {}
-    for name, (m, n) in linear_dims(model).items():
-        base = f"groups.p0.{name}."
-
-        def leaf(kind):
-            shape, dt = specs[base + kind]
-            return _layer_leaf(root, base + kind, shape[1:], jnp.dtype(dt),
-                               i, shape[0])
-
-        codes, scale = leaf("codes"), leaf("scale")
-        l, r, g = leaf("l"), leaf("r"), leaf("gscale")
-        blk = codes.shape[0] // scale.shape[0]
-        q = (codes.astype(jnp.float32).reshape(scale.shape[0], blk, n)
-             * scale[:, None, :]).reshape(codes.shape)[:m]
-        w = q + (l * g[None, :]) @ r
-        out[name] = w
-        if base + "b" in specs:
-            out[name + ".b"] = leaf("b")
-    for nm in ("norm1", "norm2"):
-        path = f"groups.p0.{nm}.g"
-        shape, dt = specs[path]
-        out[nm] = _layer_leaf(root, path, shape[1:], jnp.dtype(dt), i,
-                              shape[0])
-    return out
+def qlr_weight(root, i, specs: Dict[str, tuple], base: str, m: int,
+               branch_out: Tuple[str, ...]):
+    """Dense float32 (m, n) matrix of layer ``i`` of the container at
+    ``base``: ``Q·s + L diag(g) R``."""
+    codes, scale, l, r, g = (layer_leaf(root, base + k, specs, i, branch_out)
+                             for k in QLR_KINDS)
+    n = codes.shape[-1]
+    blk = codes.shape[0] // scale.shape[0]
+    q = (codes.astype(jnp.float32).reshape(scale.shape[0], blk, n)
+         * scale[:, None, :]).reshape(codes.shape)[:m]
+    return q + (l * g[None, :]) @ r
 
 
 # ------------------------------------------------------------ equations
@@ -129,7 +94,8 @@ def rmsnorm(x, g):
 
 
 def rope(x, pos, theta: float, kind: str):
-    """x (N, heads, hd); pos (N,). Rotates pairs (2i, 2i+1)."""
+    """x (N, heads, hd); pos (N,). Rotates pairs (2i, 2i+1): "full" every
+    dim, "half" the first hd/2 (ChatGLM's 2D rope), "none" none."""
     if kind == "none":
         return x
     hd = x.shape[-1]
@@ -144,7 +110,7 @@ def rope(x, pos, theta: float, kind: str):
 
 
 def kv_store(x):
-    """What the int4 cache gives back for x (N, kv, hd)."""
+    """What the int4 cache gives back for x (N, heads, hd)."""
     amax = jnp.max(jnp.abs(x), -1, keepdims=True)
     scale = jnp.maximum(amax, 1e-8) / KV_QMAX
     return jnp.clip(jnp.round(x / scale), -KV_QMAX, KV_QMAX) * scale
@@ -155,7 +121,8 @@ def attention(q, k, v, pos0, cs, nvalid, past_k, past_v, past_valid):
     pos0 + r, of which the first ``nvalid`` are real; cs (N,) absolute
     index of each query's first fresh key. past_k, past_v (P, KV, hd)
     hold, as stored, the keys and values of positions 0..P-1, of which
-    the first ``past_valid`` are real (P may be 0)."""
+    the first ``past_valid`` are real (P may be 0). Head h reads kv head
+    h // (H / KV)."""
     n, h, hd = q.shape
     kvh = k.shape[1]
     g = h // kvh
@@ -190,65 +157,52 @@ def attention(q, k, v, pos0, cs, nvalid, past_k, past_v, past_valid):
     return out.reshape(n, h * hd)
 
 
-def layer(wts, x, pos0, cs, nvalid, past_k, past_v, past_valid,
-          model: Dict):
-    """One decoder block over one sequence's own rows x (N, d) (see
-    :func:`attention`). Returns the block's output and the rows' keys and
-    values as the cache stores them, for a later sequence to read."""
-    n = x.shape[0]
-    h_, kvh, hd = model["n_heads"], model["n_kv_heads"], model["head_dim"]
-    pos = pos0 + jnp.arange(n)
-    h = rmsnorm(x, wts["norm1"])
-
-    def proj(name, inp):
-        y = inp @ wts[name]
-        return y + wts[name + ".b"] if name + ".b" in wts else y
-
-    q = proj("mixer.wq", h).reshape(n, h_, hd)
-    k = proj("mixer.wk", h).reshape(n, kvh, hd)
-    v = proj("mixer.wv", h).reshape(n, kvh, hd)
-    q = rope(q, pos, model["rope_theta"], model["rope"])
-    k = rope(k, pos, model["rope_theta"], model["rope"])
-    x = x + attention(q, k, v, pos0, cs, nvalid, past_k, past_v,
-                      past_valid) @ wts["mixer.wo"]
-    h2 = rmsnorm(x, wts["norm2"])
-    mlp = (jax.nn.silu(h2 @ wts["mlp.gate"]) * (h2 @ wts["mlp.up"]))
-    return x + mlp @ wts["mlp.down"], kv_store(k), kv_store(v)
-
-
 # ------------------------------------------------------------- the run
-class Reference:
-    """Jitted pieces of the reference for one configuration."""
+class Stack:
+    """The reference over a batch of requests, jitted in three pieces:
+    the embedding, one layer, the head. An architecture's ``Reference``
+    subclasses it and supplies
 
-    def __init__(self, model: Dict, weights: Dict):
+      * ``specs``: its :func:`leaf_specs` of the configuration;
+      * ``layer_weights(root, i)``: layer ``i``'s weights, regenerated;
+      * ``layer(wts, x, pos0, cs, nvalid, past, past_valid)``: one block
+        over one sequence's own rows x (N, d) (see :func:`attention`),
+        returning its output and the rows' state as the cache stores it
+        (a tuple of arrays with a leading position axis), for a later
+        sequence to read as ``past``;
+      * ``empty_past()``: that state for no positions.
+
+    The embedding (``embed.w``), final norm (``final_norm.g``) and head
+    (``lm_head.w``) are read by those paths."""
+
+    specs: Dict[str, tuple]
+
+    def __init__(self, model: Dict):
         self.model = model
-        self.specs = leaf_specs(model, weights)
-        m = model
-        kvh, hd = m["n_kv_heads"], m["head_dim"]
 
         def embed(root, tokens):
             shape, dt = self.specs["embed.w"]
             e = W.make_leaf(W.leaf_key(root, "embed.w"), "embed.w", shape,
-                            jnp.dtype(dt))
+                            jnp.dtype(dt), 1, ())
             return e[tokens]
 
         def one_layer(root, i, pre, req):
             """Layer ``i`` over every shared prefix, then over every
-            request's own rows, reading its prefix's stored K and V."""
-            wts = _layer_weights(root, i, self.specs, m)
-            none = jnp.zeros((0, kvh, hd), jnp.float32)
+            request's own rows, reading its prefix's stored state."""
+            wts = self.layer_weights(root, i)
+            none = self.empty_past()
 
             def prefix(a):
                 x, cs, nv = a
-                return layer(wts, x, 0, cs, nv, none, none, 0, m)
+                return self.layer(wts, x, 0, cs, nv, none, 0)
 
-            x_pre, k_pre, v_pre = jax.lax.map(
+            x_pre, stored = jax.lax.map(
                 prefix, (pre["x"], pre["cs"], pre["nvalid"]))
 
             def own(a):
                 x, pos0, cs, nv, grp = a
-                return layer(wts, x, pos0, cs, nv, k_pre[grp], v_pre[grp],
-                             pos0, m)[0]
+                past = tuple(s[grp] for s in stored)
+                return self.layer(wts, x, pos0, cs, nv, past, pos0)[0]
 
             x_req = jax.lax.map(own, (req["x"], req["pos0"], req["cs"],
                                       req["nvalid"], req["group"]))
@@ -258,9 +212,9 @@ class Reference:
             gs, gdt = self.specs["final_norm.g"]
             hs, hdt = self.specs["lm_head.w"]
             g = W.make_leaf(W.leaf_key(root, "final_norm.g"), "final_norm.g",
-                            gs, jnp.dtype(gdt))
+                            gs, jnp.dtype(gdt), 1, ())
             wh = W.make_leaf(W.leaf_key(root, "lm_head.w"), "lm_head.w", hs,
-                             jnp.dtype(hdt))
+                             jnp.dtype(hdt), 1, ())
             return rmsnorm(x, g) @ wh
 
         self._embed = jax.jit(embed)
@@ -293,7 +247,7 @@ def _round_up(n: int, block: int) -> int:
 
 
 def pack(seqs: Sequence[Dict], batch: int = 0, groups: int = 0):
-    """Arrays for :meth:`Reference.served_logits`: the distinct shared
+    """Arrays for :meth:`Stack.served_logits`: the distinct shared
     prefixes (each request's first ``matched`` prompt tokens, with its
     chunk width), each request's own rows, and the row of each served
     token's logits."""
@@ -351,7 +305,7 @@ def chunk_starts(n: int, eff: int, matched: int, chunk: int) -> np.ndarray:
     return np.where(p >= eff, p + 1, cs).astype(np.int32)
 
 
-def served_gaps(ref: Reference, seed: int, seqs: Sequence[Dict],
+def served_gaps(ref: Stack, seed: int, seqs: Sequence[Dict],
                 batch: int = 0, groups: int = 0) -> List[np.ndarray]:
     """For each request: the gap by which each served token's logit lies
     below the reference's best at its position."""

@@ -11,9 +11,10 @@ prefix cache the mix shares and, for a closed loop, every client's first
 request. The window then serves the mix for ``--seconds``, submitting
 to the continuous ``Engine`` and stepping it from one thread, as a
 server's pump does. Afterwards the engine is freed and a plain
-float32 reference (``bench/reference``) recomputes a seeded sample of the
-finished requests: ``correct`` says whether every served token lies
-within the configuration's limit of the reference's best logit.
+float32 reference, the architecture module the configuration names
+(``bench/reference/<module>.py``), recomputes a seeded sample of the
+finished requests: ``correct`` says whether the served tokens lie within
+the cell's limit of the reference's best logit.
 
 With ``--trace 1`` the window runs under the JAX profiler and the result
 line carries the cell's per-layer metrics (``bench/metrics/<name>.py``)

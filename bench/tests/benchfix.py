@@ -55,7 +55,9 @@ def tiny(tmp_path, monkeypatch):
     bench = root / "bench"
     for d in ("configs", "traffic", "checks"):
         (bench / d).mkdir(parents=True)
-    shutil.copytree(BENCH / "metrics", bench / "metrics")
+    for d in ("metrics", "reference"):
+        shutil.copytree(BENCH / d, bench / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     (bench / "peaks.json").write_text(json.dumps({"source": "test", "chips": {
         "cpu": {"bf16_flops": 1e12, "int8_ops": 2e12,
                 "hbm_bytes_per_s": 1e11, "hbm_bytes": 1e10}}}))
@@ -63,7 +65,7 @@ def tiny(tmp_path, monkeypatch):
     for arch, base in TINY_ARCHS.items():
         monkeypatch.setitem(rc.ARCHS, arch, rc.get_config(base).reduced())
         (bench / "configs" / f"{arch}.json").write_text(json.dumps({
-            "arch": arch, "model": tiny_model(arch),
+            "arch": arch, "reference": "dense", "model": tiny_model(arch),
             "weights": {"bits": 3, "block": 32, "rank": 16,
                         "container": "int8"},
             "serve": {"decode_batch": 4, "max_len": 256, "kv_dtype": "int4",
@@ -104,8 +106,8 @@ def tiny(tmp_path, monkeypatch):
     # is set; a test keeps its compiles to itself
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
     import jax
-    import reference.forward as rf
-    monkeypatch.setattr(rf, "PAD_TO", 256)
+    import reference.common as common
+    monkeypatch.setattr(common, "PAD_TO", 256)
     keep = {k: getattr(jax.config, k) for k in (
         "jax_persistent_cache_min_compile_time_secs",
         "jax_persistent_cache_min_entry_size_bytes")}

@@ -2,6 +2,7 @@
 generator, latency from the due time, and the refusal without a chip."""
 import json
 import os
+import re
 import subprocess
 import sys
 import time
@@ -11,7 +12,8 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from benchfix import tiny  # noqa: F401  (fixture; puts the harness on the path)
+from benchfix import cpu_device, tiny, tiny_model  # noqa: F401  (fixtures;
+# importing benchfix puts the harness on the path)
 from harness import registry
 from harness.traffic import Traffic
 
@@ -49,6 +51,101 @@ def test_new_files_are_found_by_name(tiny):
         m["name"] for m in registry.cell("tiny-mha.open")["per_layer"]]
 
 
+TOY = '''"""A test architecture: the dense block, counted its own way."""
+from reference.dense import (BRANCH_OUT, MODEL_KEYS as DENSE_KEYS, Reference,
+                             attn_macs_per_ctx, leaf_specs)
+
+MODEL_KEYS = dict(DENSE_KEYS, toy_width="d_model")
+
+
+def kv_bytes_per_token(model, kv_dtype):
+    return 12345
+'''
+
+
+def _snapshot(root):
+    return {p: p.read_bytes() for p in root.rglob("*")
+            if p.is_file() and "__pycache__" not in p.parts}
+
+
+def _add_toy_cell(tiny, **model):
+    """A configuration naming ``reference/toy.py`` and its cell, as new
+    files and a workload entry."""
+    (tiny / "reference" / "toy.py").write_text(TOY)
+    conf = json.loads((tiny / "configs" / "tiny-mha.json").read_text())
+    conf["reference"] = "toy"
+    conf["model"].update(model)
+    (tiny / "configs" / "tiny-toy.json").write_text(json.dumps(conf))
+    (tiny / "checks" / "tiny-toy.closed.json").write_text(json.dumps(
+        {"limits": {"mean_gap": 1e-4}}))
+    root = tiny.parent
+    b = json.loads((root / "BENCHMARK.json").read_text())
+    b["workloads"].append({"name": "tiny-toy.closed", "config": "tiny-toy",
+                           "traffic": "tiny_closed", "chips": 1,
+                           "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(b))
+    return conf
+
+
+def _cell(name, cpu_device, seconds=1.0):
+    import time
+    from harness.cell import Cell
+    cell = registry.cell(name)
+    dev, n = cpu_device(1)
+    return Cell(cell, registry.config(cell["config"]),
+                registry.traffic(cell["traffic"]), 9, seconds, False, dev, n,
+                t_start=time.perf_counter())
+
+
+def test_a_new_architecture_arrives_as_new_files(tiny, cpu_device):
+    """A configuration naming a new architecture module is loaded, checked
+    against the program's tree and counted from the module's numbers,
+    with every file the benchmark had left as it was."""
+    from reference import dense
+    before = _snapshot(tiny.parent)
+    conf = _add_toy_cell(tiny, toy_width=tiny_model("tiny-mha")["d_model"])
+    toy = registry.reference(conf)
+    assert toy.kv_bytes_per_token(conf["model"], "int4") == 12345
+    assert toy.MODEL_KEYS["toy_width"] == "d_model"
+    c = _cell("tiny-toy.closed", cpu_device)
+    res = c.run()
+    assert res["correct"], res["checks"]
+    assert c.work.kv_bytes_per_token == 12345
+    assert c.work.attn_macs_per_ctx == dense.attn_macs_per_ctx(conf["model"])
+    after = _snapshot(tiny.parent)
+    changed = [p for p in before if after.get(p) != before[p]]
+    assert changed == [tiny.parent / "BENCHMARK.json"]
+    for d in ("reference", "metrics"):
+        for p in (BENCH / d).glob("*.py"):
+            assert (tiny / d / p.name).read_bytes() == p.read_bytes()
+
+
+@pytest.mark.parametrize("fault", ["no key", "no module", "key differs"])
+def test_a_configuration_without_its_architecture_fails_before_weights(
+        tiny, cpu_device, monkeypatch, fault):
+    import harness.cell
+    path = tiny / "configs" / "tiny-mha.json"
+    conf = json.loads(path.read_text())
+    if fault == "no key":
+        del conf["reference"]
+        path.write_text(json.dumps(conf))
+        err, says = KeyError, str(tiny / "reference")
+    elif fault == "no module":
+        conf["reference"] = "absent"
+        path.write_text(json.dumps(conf))
+        err, says = FileNotFoundError, str(tiny / "reference" / "absent.py")
+    else:
+        _add_toy_cell(tiny, toy_width=1)
+        err, says = ValueError, "toy_width=1"
+
+    def no_weights(*a, **k):
+        raise AssertionError("weights were made")
+    monkeypatch.setattr(harness.cell, "make_params", no_weights)
+    name = "tiny-toy.closed" if fault == "key differs" else "tiny-mha.closed"
+    with pytest.raises(err, match=re.escape(says)):
+        _cell(name, cpu_device).build()
+
+
 def test_every_benchmark_name_has_its_file():
     b = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
     for w in b["workloads"]:
@@ -61,6 +158,7 @@ def test_every_benchmark_name_has_its_file():
         conf = json.loads((BENCH.parent / c["file"]).read_text())
         assert conf["source"] == c["source"]
         assert conf["reduced"] == c["reduced"]
+        registry.reference(conf)
 
 
 def _mix(name):
@@ -84,6 +182,46 @@ def test_traffic_is_seeded_and_keeps_its_sizes(name):
     for r in a.requests:
         assert np.array_equal(r.prompt[:spec["prefixes"]["tokens"]],
                               a.prefixes[r.prefix])
+
+
+def test_a_closed_loop_that_outruns_its_pool_goes_round_it():
+    from harness.window import Window
+    spec = dict(_mix("docqa"), clients=3, pool=5)
+    t = Traffic(spec, 2**31 + 3, 1000)
+    win = Window.__new__(Window)
+    win.traffic, win.next_req = t, 0
+    taken = [win.take() for _ in range(3 + 2 * 5 + 1)]
+    again = t.requests + t.requests[3:] + t.requests[3:4]
+    assert all(a is b for a, b in zip(taken, again, strict=True))
+
+
+def test_the_closed_window_closes_at_the_end_of_the_step_in_flight():
+    """The step under way when the window's seconds run out counts, with
+    its tokens and all its time, so the rate does not move in whole
+    steps."""
+    from harness.window import Flight, Window, tokens_in
+    step_s, lanes = 0.05, 4
+
+    class Engine:
+        _prefill_jobs = {}
+
+        def _admit_paged(self, budget):
+            return None
+
+        def step(self):
+            time.sleep(step_s)
+            for uid in range(1, lanes + 1):
+                self.on_token(uid, 7)
+
+    spec = dict(_mix("docqa"), clients=lanes, pool=8)
+    win = Window(Engine(), Traffic(spec, 5, 1000), None)
+    for uid in range(1, lanes + 1):
+        win.flights[uid] = Flight(req=types.SimpleNamespace(out_len=10**6),
+                                  uid=uid, due=0.0, sent=0.0)
+    t0, t1 = win.run_closed(2.5 * step_s)
+    stamps = [t for f in win.flights.values() for t in f.times]
+    assert t1 - t0 >= 3 * step_s and max(stamps) < t1
+    assert tokens_in(list(win.flights.values()), t0, t1) == 3 * lanes
 
 
 def test_docqa_draws_its_stated_distributions():

@@ -13,6 +13,7 @@ WEIGHTS = {"bits": 3, "block": 32, "rank": 16, "container": "int8"}
 def serve_with_logprobs(arch, seed, prompts, new_tokens, prefill_len=32):
     import jax
     from harness.weights import make_params
+    from reference.dense import BRANCH_OUT
     from repro.configs import get_config
     from repro.models import init_lm
     from repro.models.quantize import quantized_abstract
@@ -21,7 +22,7 @@ def serve_with_logprobs(arch, seed, prompts, new_tokens, prefill_len=32):
     ab = quantized_abstract(
         jax.eval_shape(lambda: init_lm(jax.random.PRNGKey(0), cfg)),
         rank=16)
-    params = make_params(seed, ab)
+    params = make_params(seed, ab, BRANCH_OUT)
     eng = Engine(params, cfg, ServeConfig(
         decode_batch=2, max_len=256, kv_dtype="int4", paged=True,
         page_size=16, prefill_len=prefill_len))
@@ -44,7 +45,7 @@ def serve_with_logprobs(arch, seed, prompts, new_tokens, prefill_len=32):
 @pytest.mark.parametrize("prefill_len", [32, None])
 @pytest.mark.parametrize("arch", sorted(TINY_ARCHS))
 def test_reference_matches_engine_logits(arch, prefill_len):
-    from reference.forward import Reference
+    from reference.dense import Reference
     seed = 2**33 + 5                         # past 32 signed bits
     rng = np.random.default_rng(0)
     model = tiny_model(arch)
@@ -70,7 +71,7 @@ def test_reference_matches_engine_logits(arch, prefill_len):
 def test_reading_the_chunk_from_storage_is_visible():
     """The chunk semantics matter: reading a prompt's own chunk from the
     int4 cache instead of fresh moves the logits well past rounding."""
-    from reference.forward import Reference
+    from reference.dense import Reference
     model = tiny_model("tiny-mha")
     ref = Reference(model, WEIGHTS)
     rng = np.random.default_rng(1)

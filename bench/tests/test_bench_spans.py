@@ -155,3 +155,39 @@ def test_engine_steps_hold_their_phases_in_order(tiny_engine_parts,
     assert spans.host_ms_per_step(program) > 0
     assert "serve/phase/emit" in spans.host_phases(program)
     assert "serve/prefill_chunk" in spans.host_phases(program)
+
+
+def test_decode_counters_match_the_lane_table(tiny_engine_parts):
+    """The engine's ``decode_slot_steps`` and ``decode_context_tokens``,
+    which the traced run reads around its window, count what the lane
+    table says at each decode dispatch: the lanes not mid-prefill, and
+    their prompts plus tokens so far."""
+    from repro.serve import Engine, Request, ServeConfig
+    cfg, params = tiny_engine_parts
+    eng = Engine(params, cfg, ServeConfig(
+        max_len=64, decode_batch=2, max_new_tokens=6, prefill_len=16,
+        paged=True, page_size=8))
+    eng.warmup()
+    seen, decode = [], eng._decode
+
+    def noted(*a):
+        st = eng.sched.table.active
+        live = [s for s in st if s not in eng._prefill_jobs]
+        seen.append((len(live), sum(st[s].prompt_len + len(st[s].tokens)
+                                    for s in live)))
+        return decode(*a)
+
+    eng._decode = noted
+    rng = np.random.default_rng(4)
+    for uid, n in enumerate((6, 37, 20, 41)):     # some span three chunks
+        eng.submit(Request(uid=uid, prompt=rng.integers(
+            0, cfg.vocab, size=n).astype(np.int32)))
+    s0 = eng.stats()
+    eng.drain()
+    s1 = eng.stats()
+    got = {k: s1[k] - s0[k] for k in ("decode_steps", "decode_slot_steps",
+                                      "decode_context_tokens")}
+    assert got == {"decode_steps": len(seen),
+                   "decode_slot_steps": sum(n for n, _ in seen),
+                   "decode_context_tokens": sum(c for _, c in seen)}
+    assert len({n for n, _ in seen}) > 1        # lanes filled and emptied

@@ -8,6 +8,7 @@ import pytest
 import benchfix  # noqa: F401  (puts the harness on the path)
 from harness import counts, layers, registry
 from harness import trace as T
+from reference import dense
 
 # op names as the TPU profiler gives them: the HLO instruction
 QLR = ("%mxint_lowrank_matmul.51 = f32[2,3072]{1,0} custom-call(f32[2,3072] "
@@ -20,6 +21,8 @@ ATTN = ("%_decode_attention_pallas_paged.7 = f32[2,32,1,96]{3,2,1,0} "
 
 PHI3 = {"n_layers": 32, "d_model": 3072, "n_heads": 32, "n_kv_heads": 32,
         "head_dim": 96, "d_ff": 8192, "vocab": 32064}
+CHATGLM3 = {"n_layers": 28, "d_model": 4096, "n_heads": 32, "n_kv_heads": 2,
+            "head_dim": 128, "d_ff": 13696, "vocab": 65024}
 
 
 def _ev(name, start_us, dur_us):
@@ -78,9 +81,20 @@ def test_top_ops_leave_out_the_loop_around_them():
 def test_kv_bytes_per_token_phi3_int4():
     # 32 layers x 32 kv heads x 96 x (K, V) at half a byte, plus an f32
     # scale per (layer, head, K/V)
-    assert counts.kv_bytes_per_token(PHI3, "int4") == \
+    assert dense.kv_bytes_per_token(PHI3, "int4") == \
         32 * 32 * 96 + 32 * 32 * 2 * 4 == 106496
-    assert counts.kv_bytes_per_token(PHI3, "bf16") == 2 * 32 * 32 * 96 * 2
+    assert dense.kv_bytes_per_token(PHI3, "bf16") == 2 * 32 * 32 * 96 * 2
+
+
+def test_dense_module_counts_chatglm3_and_attention_work():
+    # 28 layers x 2 kv heads x 128 x (K, V) at half a byte, plus scales
+    assert dense.kv_bytes_per_token(CHATGLM3, "int4") == \
+        28 * 2 * 128 + 28 * 2 * 2 * 4 == 7616
+    assert dense.kv_bytes_per_token(CHATGLM3, "int8") == \
+        2 * 28 * 2 * 128 + 28 * 2 * 2 * 4
+    # q.k and p.v in every query head of every layer
+    assert dense.attn_macs_per_ctx(PHI3) == 2 * 32 * 32 * 96 == 196608
+    assert dense.attn_macs_per_ctx(CHATGLM3) == 2 * 28 * 32 * 128 == 229376
 
 
 def test_step_work_from_served_shapes():
@@ -92,7 +106,7 @@ def test_step_work_from_served_shapes():
     cfg = get_config("phi3-mini-3.8b")
     ab = quantized_abstract(
         jax.eval_shape(lambda: init_lm(jax.random.PRNGKey(0), cfg)), rank=16)
-    w = counts.step_work(ab, PHI3, "int4")
+    w = counts.step_work(ab, PHI3, "int4", dense)
     d, ff, L = 3072, 8192, 32
     mn = 4 * d * d + 3 * d * ff                  # wq wk wv wo + up gate down
     assert w.qlr_macs_per_row == L * (mn + 16 * (4 * (d + d)
@@ -104,6 +118,8 @@ def test_step_work_from_served_shapes():
     assert w.head_macs_per_row == d * 32064
     assert w.dense_weight_bytes == 4 * (d * 32064 + d + 2 * L * d)
     assert w.attn_macs_per_ctx == 2 * L * 32 * 96
+    assert w.kv_bytes_per_token == 106496
+    assert w.qlr_calls_per_step == 7 * L         # wq wk wv wo up gate down
     del jnp
 
 
@@ -117,14 +133,15 @@ def test_roofline_picks_the_binding_bound():
 def test_kernel_share_and_step_metrics_on_a_known_trace():
     tr = T.from_profile(_profile())
     w = counts.StepWork(qlr_weight_bytes=7 * 2 * 1000, qlr_macs_per_row=10,
-                        qlr_io_per_row=0, dense_weight_bytes=0,
-                        head_macs_per_row=0, kv_bytes_per_token=10,
-                        attn_macs_per_ctx=0)
+                        qlr_io_per_row=0, qlr_calls_per_step=7 * 2,
+                        dense_weight_bytes=0, head_macs_per_row=0,
+                        kv_bytes_per_token=10, attn_macs_per_ctx=0)
+    # two decode steps, each of 2 live lanes over 100 tokens of context
     run = layers.TracedRun(
-        trace=tr, window_s=300e-6,
-        decode_calls=[layers.DecodeCall(live=2, ctx=100)] * 2, work=w,
-        model={"n_layers": 2}, lanes=2,
-        peaks={"bf16_flops": 1e15, "hbm_bytes_per_s": 1e9}, counters={},
+        trace=tr, window_s=300e-6, work=w, model={"n_layers": 2}, lanes=2,
+        peaks={"bf16_flops": 1e15, "hbm_bytes_per_s": 1e9},
+        counters={"decode_steps": 2, "decode_slot_steps": 4,
+                  "decode_context_tokens": 200},
         gen_lags_s=[])
     # 2 decode Q+LR calls (the prefill one is left out) of 1000 bytes each at 1 GB/s = 2 us over 60 us
     assert layers.qlr_roofline(run) == pytest.approx(100 * 2e-6 / 60e-6)
